@@ -83,4 +83,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.core.compat import enable_compile_cache
+
+    enable_compile_cache()
     main()

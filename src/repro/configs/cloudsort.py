@@ -91,6 +91,46 @@ def ooc_smoke_plan():
     )
 
 
+#: The chip smoke deployment (chip_smoke.py): the Indy sort at the real
+#: 100-byte record, >= 4 map waves of 2^24 records (1.68 GB, the paper's
+#: 2 GB map block rounded down to a power of two) — 6.7 GB of input.
+CHIP_SMOKE_RECORDS_PER_WAVE = 1 << 24
+CHIP_SMOKE_WAVES = 4
+
+
+def chip_smoke_plan(records_per_wave: int = CHIP_SMOKE_RECORDS_PER_WAVE):
+    """Out-of-core schedule for one TPU chip (chip_smoke.py).
+
+    Default lowerings (impl="ref", reduce_merge_impl="numpy"); the chip
+    smoke swaps in the device lowerings for its second phase. Input
+    objects of 2^20 records (~105 MB), 8 MiB map-download chunks, 2
+    streaming rounds per wave, R1=16 output partitions per mesh worker
+    written in 2^18-record (26 MB) multipart parts, and 8 concurrent
+    streaming merges fetching <= 4 MiB per run under a 256 MiB budget.
+    A wave working set is 2 x 4 B x records_per_wave of device keys and
+    ids, about 0.7 GB of compiled temporaries at 2^24 — a small share of
+    a 16 GB v5e chip. Lazily imported so configs stay importable without
+    jax.
+    """
+    from repro.core.external_sort import ExternalSortPlan
+
+    return ExternalSortPlan(
+        records_per_wave=records_per_wave,
+        num_rounds=2,
+        reducers_per_worker=16,
+        payload_words=23,
+        impl="ref",
+        input_records_per_partition=min(1 << 20, records_per_wave),
+        output_part_records=1 << 18,
+        store_chunk_bytes=8 << 20,
+        merge_chunk_bytes=4 << 20,
+        parallel_reducers=8,
+        reduce_memory_budget_bytes=256 << 20,
+        part_upload_fanout=2,
+        reduce_merge_impl="numpy",
+    )
+
+
 def cluster_smoke_plan(num_workers: int = 4, *, base=None, runs: int = 16):
     """(ExternalSortPlan, ClusterPlan) for cluster smoke runs.
 

@@ -1,50 +1,54 @@
-"""jax API compatibility shims.
+"""JAX set-up in one place: meshes, shard_map and the compile cache.
 
-The repo is written against the current jax API — ``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh`` with ``axis_types`` — but the baked
-toolchain on some containers ships jax 0.4.x, where the same functionality
-lives under ``jax.experimental.shard_map`` (``check_rep``) and ``make_mesh``
-has no axis typing. Every mesh/shard_map construction in this repo goes
-through these two wrappers so the whole system (distributed sort, MoE
-dispatch, out-of-core driver, examples, tests) runs on both.
+Every mesh/shard_map construction in this repo (distributed sort, MoE
+dispatch, out-of-core driver, examples, tests) goes through these
+wrappers, so the axis typing and the compiled-cost shape are chosen once.
+Every entry point (chip_smoke.py, examples/, shuffle/worker_main) calls
+`enable_compile_cache()` before its first compile.
 """
 from __future__ import annotations
 
+import os
+
 import jax
+
+#: The persistent compile cache when JAX_COMPILATION_CACHE_DIR is unset:
+#: a fixed path inside the checkout (the path is part of the cache key,
+#: so it never moves with a pid, a tempdir or a timestamp).
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and
+    nothing is set here; otherwise the cache lives at DEFAULT_CACHE_DIR.
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` where it exists; the 0.4.x experimental fallback
-    otherwise (``check_vma`` maps onto the old ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=bool(check_vma),
-    )
+    """``jax.shard_map`` with the repo's keyword set."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version
-    (0.4.x wraps the per-program properties in a single-element list)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``Compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
-def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_shapes = tuple(axis_shapes)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types, over `devices` (default:
+    all of them)."""
     axis_names = tuple(axis_names)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(axis_shapes, axis_names)
     return jax.make_mesh(
-        axis_shapes, axis_names, axis_types=(axis_type.Auto,) * len(axis_names)
-    )
+        tuple(axis_shapes), axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)

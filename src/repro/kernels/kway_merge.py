@@ -25,8 +25,9 @@ idx), so no fallback path is needed.
 Three lowerings of the same network, pinned bit-identical by
 tests/test_kernels.py:
 
-  * `merge_sorted_pairs_indexed` — the pallas_call kernel (grid over row
-    pairs; Mosaic on a real TPU, interpret mode on CPU);
+  * `merge_sorted_pairs_indexed` — the Pallas kernels of
+    kernels/bitonic_sort.py (row tiles in VMEM plus global passes over
+    HBM tiles; Mosaic on a real TPU, interpret mode on CPU);
   * the jit'd plain-jnp network — identical math without the pallas_call
     wrapper; on CPU this is the production lowering (XLA-compiled rather
     than Python-interpreted kernel bodies, ~100x faster than interpret
@@ -45,12 +46,13 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
 from repro.kernels import ref as _ref
+from repro.kernels.bitonic_sort import merge_pairs
 
 PAD_KEY = 0xFFFFFFFF
 PAD_VAL = 0xFFFFFFFF
+MIN_RUN = 512  # fragment rows pad to >= this: merged rows of >= (8, 128)
 
 
 def _triple_swap_needed(k0, v0, i0, k1, v1, i1):
@@ -63,8 +65,7 @@ def _compare_exchange_idx(keys, vals, idx, dist: int, window: int):
     `window`, carrying the int32 ordinal as the last lex operand.
 
     keys/vals/idx: (..., B). Static dist/window (powers of two); leading
-    dims broadcast (the jnp lowering batches rows, the Pallas kernel
-    passes 1-D blocks).
+    dims broadcast (the jnp lowering batches rows).
     """
     shape = keys.shape
     b = shape[-1]
@@ -78,7 +79,7 @@ def _compare_exchange_idx(keys, vals, idx, dist: int, window: int):
     i0, i1 = ir[..., 0, :], ir[..., 1, :]
 
     # Ascending iff the stage window this group falls in has even index
-    # (same direction rule as bitonic_sort._compare_exchange).
+    # (the direction rule of kernels/bitonic_sort.py).
     g = jax.lax.broadcasted_iota(jnp.int32, (groups, 1), 0)
     asc = ((g * (2 * dist)) // window) % 2 == 0
 
@@ -104,53 +105,22 @@ def _merge_network_idx(keys, vals, idx):
     return keys, vals, idx
 
 
-def _merge_pair_indexed_kernel(ak_ref, av_ref, ai_ref, bk_ref, bv_ref,
-                               bi_ref, ok_ref, ov_ref, oi_ref):
-    ak = ak_ref[...].reshape(-1)
-    av = av_ref[...].reshape(-1)
-    ai = ai_ref[...].reshape(-1)
-    # Reverse the second run: ascending ++ descending == bitonic.
-    bk = bk_ref[...].reshape(-1)[::-1]
-    bv = bv_ref[...].reshape(-1)[::-1]
-    bi = bi_ref[...].reshape(-1)[::-1]
-    keys = jnp.concatenate([ak, bk])
-    vals = jnp.concatenate([av, bv])
-    idx = jnp.concatenate([ai, bi])
-    keys, vals, idx = _merge_network_idx(keys, vals, idx)
-    ok_ref[...] = keys.reshape(ok_ref.shape)
-    ov_ref[...] = vals.reshape(ov_ref.shape)
-    oi_ref[...] = idx.reshape(oi_ref.shape)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def merge_sorted_pairs_indexed(
     a_keys: jax.Array, a_vals: jax.Array, a_idx: jax.Array,
     b_keys: jax.Array, b_vals: jax.Array, b_idx: jax.Array,
-    *, interpret: bool = True,
+    *, interpret: bool,
 ):
     """Merge row i of a_* with row i of b_* (each (n, L), rows sorted
     lexicographically on (key, val, idx)). Returns (keys, vals, idx) of
     shape (n, 2L), each row triple-lex sorted. L must be a power of two.
+    `interpret` is the caller's choice (True: the Pallas interpreter;
+    False: Mosaic).
     """
     assert a_keys.shape == a_vals.shape == a_idx.shape
     assert a_keys.shape == b_keys.shape == b_vals.shape == b_idx.shape
-    n, run = a_keys.shape
-    assert run & (run - 1) == 0, f"run length {run} must be a power of two"
-    in_blk = pl.BlockSpec((1, run), lambda i: (i, 0))
-    out_blk = pl.BlockSpec((1, 2 * run), lambda i: (i, 0))
-    out_sd = (
-        jax.ShapeDtypeStruct((n, 2 * run), a_keys.dtype),
-        jax.ShapeDtypeStruct((n, 2 * run), a_vals.dtype),
-        jax.ShapeDtypeStruct((n, 2 * run), a_idx.dtype),
-    )
-    return pl.pallas_call(
-        _merge_pair_indexed_kernel,
-        grid=(n,),
-        in_specs=[in_blk] * 6,
-        out_specs=(out_blk, out_blk, out_blk),
-        out_shape=out_sd,
-        interpret=interpret,
-    )(a_keys, a_vals, a_idx, b_keys, b_vals, b_idx)
+    return merge_pairs((a_keys, a_vals, a_idx), (b_keys, b_vals, b_idx),
+                       interpret=interpret)
 
 
 def _merge_pairs_body(ak, av, ai, bk, bv, bi):
@@ -176,6 +146,13 @@ def _tournament_body(keys, vals, idx, merge_pairs):
 @jax.jit
 def _tournament_network(keys, vals, idx):
     return _tournament_body(keys, vals, idx, _merge_pairs_body)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _tournament_pallas(keys, vals, idx, *, interpret: bool):
+    return _tournament_body(
+        keys, vals, idx,
+        functools.partial(merge_sorted_pairs_indexed, interpret=interpret))
 
 
 def _on_cpu() -> bool:
@@ -210,22 +187,19 @@ def kway_merge_indexed(keys, vals, idx, *, impl: str = "pallas"):
         return _tournament_network(jnp.asarray(keys), jnp.asarray(vals),
                                    jnp.asarray(idx))
     assert impl == "pallas", f"unknown impl {impl!r}"
-    interp = _on_cpu()
-
-    def merge_pairs(ak, av, ai, bk, bv, bi):
-        return merge_sorted_pairs_indexed(ak, av, ai, bk, bv, bi,
-                                          interpret=interp)
-
-    return _tournament_body(jnp.asarray(keys), jnp.asarray(vals),
-                            jnp.asarray(idx), merge_pairs)
+    # The Pallas interpreter on the CPU backend only; Mosaic everywhere
+    # else.
+    return _tournament_pallas(jnp.asarray(keys), jnp.asarray(vals),
+                              jnp.asarray(idx), interpret=_on_cpu())
 
 
 def _pad_window(frags, total: int):
     """Pack an emit window's fragments into (K, L) power-of-two arrays
     padded with lex-max records whose ordinal is `total` (past every real
-    record, so pads always sort last)."""
+    record, so pads always sort last). L is at least MIN_RUN, so every
+    merged row fills whole (8, 128) tiles."""
     kp = _next_pow2(len(frags))
-    lp = _next_pow2(max(max(f[0].size for f in frags), 1))
+    lp = _next_pow2(max(max(f[0].size for f in frags), MIN_RUN))
     keys = np.full((kp, lp), PAD_KEY, np.uint32)
     vals = np.full((kp, lp), PAD_VAL, np.uint32)
     idx = np.full((kp, lp), total, np.int32)
@@ -263,7 +237,9 @@ def merge_fragments_device(frags, payload_words: int, *,
     assert total < 2**31, "emit window exceeds int32 ordinal range"
     keys, vals, idx = _pad_window(frags, total)
     if impl == "pallas" and _on_cpu():
-        impl = "network"  # identical math, XLA-compiled (see docstring)
+        # CPU backend only: the same math as plain jnp, XLA-compiled
+        # (see docstring); accelerators run the pallas_call.
+        impl = "network"
     mk, mv, mi = kway_merge_indexed(keys, vals, idx, impl=impl)
     mk = np.asarray(mk)[:total]
     mv = np.asarray(mv)[:total]

@@ -10,43 +10,18 @@ pass of compare-exchanges sorts completely. k-way merging becomes a
 tournament of pairwise merges (log2 k rounds), each round fully
 data-parallel — see kernels/ops.py:kway_merge.
 
-Grid: one program per pair of runs. Each program loads both runs (2L
-records) into VMEM, reverses the second, runs the merge network, and writes
-the merged 2L run. Static power-of-two shapes throughout.
+The network and its tiling are kernels/bitonic_sort.py's: each merged
+row of 2L records is laid out as (2L/128, 128) tiles, distances of a VMEM
+tile and above run as global passes over HBM tiles, the rest in one local
+kernel per tile. Static power-of-two shapes throughout.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.bitonic_sort import _compare_exchange
-
-
-def _merge_network(keys, vals):
-    """Sort a bitonic (B,) sequence: substages at distance B/2 ... 1, all ascending."""
-    b = keys.shape[0]
-    dist = b // 2
-    while dist >= 1:
-        # window == b: a single ascending window covering the whole block.
-        keys, vals = _compare_exchange(keys, vals, dist, b)
-        dist //= 2
-    return keys, vals
-
-
-def _merge_pair_kernel(ak_ref, av_ref, bk_ref, bv_ref, ok_ref, ov_ref):
-    ak = ak_ref[...].reshape(-1)
-    av = av_ref[...].reshape(-1)
-    # Reverse the second run: ascending ++ descending == bitonic.
-    bk = bk_ref[...].reshape(-1)[::-1]
-    bv = bv_ref[...].reshape(-1)[::-1]
-    keys = jnp.concatenate([ak, bk])
-    vals = jnp.concatenate([av, bv])
-    keys, vals = _merge_network(keys, vals)
-    ok_ref[...] = keys.reshape(ok_ref.shape)
-    ov_ref[...] = vals.reshape(ov_ref.shape)
+from repro.kernels.bitonic_sort import merge_pairs
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -56,27 +31,14 @@ def merge_sorted_pairs(
     b_keys: jax.Array,
     b_vals: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Merge row i of a_* with row i of b_* (each (n, L), rows lex-sorted).
 
     Returns (keys, vals) of shape (n, 2L), each row lex-sorted ascending.
-    L must be a power of two.
+    L must be a power of two. `interpret` is the caller's choice (True:
+    the Pallas interpreter; False: Mosaic).
     """
     assert a_keys.shape == a_vals.shape == b_keys.shape == b_vals.shape
-    n, run = a_keys.shape
-    assert run & (run - 1) == 0, f"run length {run} must be a power of two"
-    in_blk = pl.BlockSpec((1, run), lambda i: (i, 0))
-    out_blk = pl.BlockSpec((1, 2 * run), lambda i: (i, 0))
-    out_sd = (
-        jax.ShapeDtypeStruct((n, 2 * run), a_keys.dtype),
-        jax.ShapeDtypeStruct((n, 2 * run), a_vals.dtype),
-    )
-    return pl.pallas_call(
-        _merge_pair_kernel,
-        grid=(n,),
-        in_specs=[in_blk, in_blk, in_blk, in_blk],
-        out_specs=(out_blk, out_blk),
-        out_shape=out_sd,
-        interpret=interpret,
-    )(a_keys, a_vals, b_keys, b_vals)
+    return merge_pairs((a_keys, a_vals), (b_keys, b_vals),
+                       interpret=interpret)

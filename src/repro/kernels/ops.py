@@ -32,6 +32,8 @@ PAD_VAL = jnp.uint32(0xFFFFFFFF)
 
 
 def _on_cpu() -> bool:
+    """The kernels run in the Pallas interpreter on the CPU backend only;
+    every other backend compiles them with Mosaic."""
     return jax.default_backend() == "cpu"
 
 
@@ -121,6 +123,8 @@ def partition_offsets(sorted_keys: jax.Array, boundaries: jax.Array, *, impl: st
     if impl == "ref":
         return _ref.partition_offsets_ref(sorted_keys, boundaries)
     shape = sorted_keys.shape
+    if boundaries.shape[0] == 0:
+        return jnp.zeros(shape[:-1] + (0,), jnp.int32)
     keys2 = sorted_keys.reshape((-1, shape[-1]))
     out = partition_offsets_blocks(keys2, boundaries, interpret=_on_cpu())
     return out.reshape(shape[:-1] + (boundaries.shape[0],))

@@ -20,12 +20,16 @@ bit-for-bit against `searchsorted_reference` below by
 tests/test_shuffle.py's property tests.
 
 Instead of a branchy binary search (log n dependent steps), the kernel
-computes offsets[j] = sum_i [key_i < b_j] by streaming the sorted block
-through VMEM in tiles and accumulating a (R,) counter vector — a pure
-vector-compare + reduce pipeline at 8x128 lane width, O(n*R/8/128) VPU
-cycles with perfect utilization and no data-dependent control flow.
+computes offsets[j] = sum_i [key_i < b_j] by streaming the sorted row
+through VMEM and accumulating counts — a pure vector-compare + reduce
+pipeline at 8x128 lane width with no data-dependent control flow.
 
-Grid: one program per key block; boundaries are broadcast to every program.
+Grid: (row, key tile). A row of B keys is laid out as (B/128, 128); each
+program loads one (KEY_TILE/128, 128) tile, so a row is never loaded
+whole. The boundaries ride in SMEM (scalar prefetch). For each boundary
+the program adds the tile's per-lane counts into row j of its (R, 128)
+output block, which stays resident across the row's key tiles; the lane
+sums are folded outside the kernel.
 """
 from __future__ import annotations
 
@@ -34,8 +38,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-KEY_TILE = 2048  # keys compared per inner step; R x KEY_TILE bools in flight
+KEY_TILE = 1 << 16  # keys per grid step (256 KiB of uint32 in VMEM)
 
 
 def searchsorted_reference(sorted_keys, boundaries):
@@ -53,49 +58,56 @@ def searchsorted_reference(sorted_keys, boundaries):
     ]).astype(np.int32)
 
 
-def _partition_kernel(keys_ref, bounds_ref, out_ref, *, key_tile: int):
-    b = keys_ref.shape[-1]
-    r = bounds_ref.shape[-1]
-    bounds = bounds_ref[...].reshape(r)
+def _partition_kernel(bounds_ref, keys_ref, out_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    def body(t, acc):
-        tile = jax.lax.dynamic_slice(
-            keys_ref[...].reshape(-1), (t * key_tile,), (key_tile,)
-        )
-        # (r, key_tile) compare, reduce over keys.
-        lt = (tile[None, :] < bounds[:, None]).astype(jnp.int32)
-        return acc + jnp.sum(lt, axis=1)
+    keys = keys_ref[...]
 
-    steps = b // key_tile
-    acc = jnp.zeros((r,), jnp.int32)
-    acc = jax.lax.fori_loop(0, steps, body, acc)
-    out_ref[...] = acc.reshape(out_ref.shape)
+    def body(j, carry):
+        below = (keys < bounds_ref[j]).astype(jnp.int32)
+        out_ref[pl.ds(j, 1), :] += jnp.sum(below, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[0], body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def partition_offsets_blocks(
-    sorted_keys: jax.Array, boundaries: jax.Array, *, interpret: bool = True
+    sorted_keys: jax.Array, boundaries: jax.Array, *, interpret: bool
 ):
     """offsets[i, j] = #{k in row i : k < boundaries[j]}.
 
     sorted_keys: (num_blocks, B) uint32, rows ascending (sortedness is not
     required for correctness of the count, only for the offsets-as-slices
-    interpretation). boundaries: (R,) uint32 ascending.
-    Returns (num_blocks, R) int32.
+    interpretation). boundaries: (R,) uint32 ascending, R >= 1. Returns
+    (num_blocks, R) int32. `interpret` is the caller's choice (True: the
+    Pallas interpreter; False: Mosaic).
     """
     nb, b = sorted_keys.shape
     (r,) = boundaries.shape
-    key_tile = min(KEY_TILE, b)
-    assert b % key_tile == 0
-    kernel = functools.partial(_partition_kernel, key_tile=key_tile)
-    return pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, b), lambda i: (i, 0)),
-            pl.BlockSpec((r,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, r), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, r), jnp.int32),
+    lanes = min(b, 128)
+    rows = -(-b // lanes)
+    tile_rows = min(rows, max(KEY_TILE // lanes, 1))
+    rows = -(-rows // tile_rows) * tile_rows
+    if rows * lanes != b:
+        # uint32 max is below no boundary, so pad keys are never counted.
+        sorted_keys = jnp.pad(sorted_keys, ((0, 0), (0, rows * lanes - b)),
+                              constant_values=jnp.uint32(0xFFFFFFFF))
+    counts = pl.pallas_call(
+        _partition_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb, rows // tile_rows),
+            in_specs=[pl.BlockSpec((None, tile_rows, lanes),
+                                   lambda i, t, bounds: (i, t, 0))],
+            out_specs=pl.BlockSpec((None, r, lanes),
+                                   lambda i, t, bounds: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((nb, r, lanes), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(sorted_keys, boundaries)
+    )(boundaries, sorted_keys.reshape(nb, rows, lanes))
+    return jnp.sum(counts, axis=-1, dtype=jnp.int32)

@@ -2,7 +2,7 @@
 
 Defined as functions (never module-level constants) so importing this
 module never touches jax device state. Mesh construction goes through
-core/compat.py so the same code runs on old (0.4.x) and current jax.
+core/compat.py, like every mesh in the repo.
 """
 from __future__ import annotations
 

@@ -5,10 +5,17 @@ device mesh and one GIL: every wave sort serializes on the single
 device lock, so thread fleets show request-level parallelism but no
 COMPUTE parallelism. A ProcessWorker spawns `repro.shuffle.worker_main`
 with its own interpreter and its own JAX runtime (the child env pins
-`XLA_FLAGS=--xla_force_host_platform_device_count=N` before the first
-jax import), talking line-JSON over stdin/stdout — so a W=4 process
-fleet sorts four waves concurrently for real, which is exactly what
-benchmarks/bench_elastic.py measures against the thread fleet.
+`JAX_PLATFORMS=cpu` and `XLA_FLAGS=--xla_force_host_platform_device_count=N`
+before the first jax import), talking line-JSON over stdin/stdout — so a
+W=4 process fleet sorts four waves concurrently for real, which is
+exactly what benchmarks/bench_elastic.py measures against the thread
+fleet.
+
+The process fleet is CPU emulation only. A chip belongs to one process
+at a time, so on an accelerator host the children could neither load it
+nor share it with the parent; until each child owns a chip of its own,
+a ProcessWorker refuses to spawn anything when the parent's JAX backend
+is not the CPU, rather than hang or quietly sort on the host.
 
 The parent half implements the same `Worker` protocol the drivers
 already speak — `run_map_phase` / `run_reduce_phase` drain the driver's
@@ -112,7 +119,18 @@ class ProcessWorker(Worker):
                  ready_timeout_s: float = 180.0):
         import dataclasses
 
+        import jax
+
         import repro
+
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"ProcessWorker {name!r}: the parent's JAX backend is "
+                f"{backend!r}. Process fleets emulate workers on the CPU "
+                "only (the chip belongs to one process); run the job with "
+                "ThreadWorkers on an accelerator host, or set "
+                "JAX_PLATFORMS=cpu.")
 
         self.name = name
         self.store = _RemoteStats()
@@ -141,6 +159,7 @@ class ProcessWorker(Worker):
         # import root from its search path instead.
         src_dir = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={mesh_devices}")
         env["PYTHONPATH"] = src_dir + (

@@ -4,9 +4,10 @@ The child half of shuffle/procworker.ProcessWorker. The parent writes
 one JSON spec line to stdin; the child rebuilds its world from it — a
 store handle over the SHARED filesystem root (its own middleware stack:
 metrics, plus an optional latency/bandwidth fault profile to make this
-one worker a straggler), its own JAX runtime (XLA_FLAGS from the parent
-env pins the host device count BEFORE the first jax import), the sort
-plan and mesh — then speaks a line-delimited JSON protocol:
+one worker a straggler), its own CPU JAX runtime (JAX_PLATFORMS=cpu and
+XLA_FLAGS from the parent env pin the platform and host device count
+BEFORE the first jax import), the sort plan and mesh — then speaks a
+line-delimited JSON protocol:
 
   child -> parent                      parent -> child
   {"ev":"ready"}                       {"cmd":"phase","phase":"map"}
@@ -119,12 +120,13 @@ def main() -> int:
 
     import numpy as np
 
-    from repro.core.compat import make_mesh
+    from repro.core.compat import enable_compile_cache, make_mesh
     from repro.core.external_sort import ExternalSortPlan
     from repro.io.backends import ObjectNotFound
     from repro.shuffle import runtime as rt
     from repro.shuffle.sort import DeviceMergeReduceOp, MergeReduceOp, SortMapOp
 
+    enable_compile_cache()
     store = _build_store(spec["store"])
     bucket = spec["bucket"]
     plan = ExternalSortPlan(**spec["plan"])

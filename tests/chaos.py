@@ -23,7 +23,9 @@ machinery did, not just that bytes came out right.
 """
 import os
 
-# Before the first jax import: the schedules need an 8-device host mesh.
+# Before the first jax import: the schedules need an 8-device host mesh,
+# emulated on the CPU backend (never a chip, whatever the environment).
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 

@@ -3,7 +3,9 @@
 jax fixes the device count at first backend init, so multi-device tests
 (shard_map, all_to_all) run in fresh subprocesses with
 --xla_force_host_platform_device_count set. Single-device tests run
-in-process and see 1 device, as required.
+in-process and see 1 device, as required. The child always runs on the
+CPU backend (JAX_PLATFORMS=cpu): it emulates devices, and it must never
+reach for a chip, whatever the caller's environment.
 
 The driver script is piped over stdin and compiled with ``optimize=0``,
 NOT passed to ``python -c``: under CI's PYTHONOPTIMIZE=1 job a ``-c``
@@ -28,6 +30,7 @@ _WRAPPER = ("import sys; _src = sys.stdin.read(); "
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(

@@ -176,6 +176,27 @@ def test_merge_pairs_adversarial(case):
     np.testing.assert_array_equal(mv, rv)
 
 
+@pytest.mark.parametrize("case", ADVERSARIAL)
+def test_tiled_network_global_passes(case):
+    # Rows longer than the VMEM tile run global passes over HBM tiles
+    # between local tile kernels; a 256-record tile exercises them here.
+    from repro.kernels.bitonic_sort import merge_pairs, sort_rows
+
+    shape, half = (2, 2048), 1024
+    k = jnp.asarray(_adversarial_keys(case, shape))
+    v = jnp.asarray(RNG.integers(0, 3, shape, dtype=np.uint32))
+    i = jnp.asarray(RNG.integers(0, 2**20, shape, dtype=np.int32))
+    got = jax.jit(lambda *x: sort_rows(x, interpret=True, tile=256))(k, v, i)
+    for g, w in zip(got, ref.sort_kvi_ref(k, v, i)):
+        np.testing.assert_array_equal(g, w)
+    a = ref.sort_kvi_ref(k[:, :half], v[:, :half], i[:, :half])
+    b = ref.sort_kvi_ref(k[:, half:], v[:, half:], i[:, half:])
+    got = jax.jit(lambda a, b: merge_pairs(a, b, interpret=True, tile=256))(
+        a, b)
+    for g, w in zip(got, ref.merge_kvi_ref(*a, *b)):
+        np.testing.assert_array_equal(g, w)
+
+
 @hp.given(
     st.lists(st.integers(0, 2**32 - 1), min_size=128, max_size=128),
     st.integers(0, 2**32 - 1),

@@ -3,7 +3,7 @@
 Tracks, from this PR onward: end-to-end sorted records/s at a fixed
 out-of-core oversubscription, the measured GET/PUT request counts (the
 Table-2 access legs), the measured-TCO total for the run, and the
-per-phase span timeline (map wait/compute/spill, reduce
+per-phase span timeline (map wait/device sort/spill, reduce
 fetch/merge/upload) so stage overlap is a number, not a narrative. Runs
 on however many devices the harness process has (typically 1) — the
 point is the store path, not the collective.
@@ -84,7 +84,7 @@ def run(full: bool = False):
     # overlapped across threads — the §2.5 claim, measured).
     ph = rep.phase_seconds
     stage_wall = {"map": rep.map_seconds, "reduce": rep.reduce_seconds}
-    for phase in ("map.wait", "map.compute", "map.spill",
+    for phase in ("map.wait", "map.device_sort", "map.spill",
                   "reduce.fetch", "reduce.merge", "reduce.upload"):
         secs = ph.get(phase, 0.0)
         denom = stage_wall[phase.split(".", 1)[0]]

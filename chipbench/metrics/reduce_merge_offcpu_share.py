@@ -1,0 +1,16 @@
+"""reduce_merge_offcpu_share: the share of the reduce's merge spans
+(reduce.merge, on the scheduler's reducer threads) spent off the CPU,
+100 x (1 - summed cpu_s / summed span seconds) over the spans that carry
+the thread's CPU seconds (`cpu_s`). None where no such span was recorded."""
+
+
+def read(run):
+    wall = cpu = 0.0
+    for job in run.jobs:
+        for ev in job.events:
+            if ev["name"] == "reduce.merge" and "cpu_s" in ev:
+                wall += ev["dur"]
+                cpu += ev["cpu_s"]
+    if wall <= 0:
+        return None
+    return 100.0 * (1.0 - cpu / wall)

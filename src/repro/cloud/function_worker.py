@@ -192,7 +192,7 @@ def invoke(event: dict, *, gate: Callable[[int], bool] | None = None,
     # invocation's own retry-inflated request counts are its bill.
     store = MetricsMiddleware(_resolve_store(event["store"]))
     control = rt.JobControl()
-    timeline = rt.PhaseTimeline(origin=time.perf_counter())
+    timeline = rt.PhaseTimeline()
     committed: list[int] = []
     requeued: list[int] = []
 

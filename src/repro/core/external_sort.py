@@ -88,10 +88,10 @@ hardcoded 6M/1M constants.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import threading
-import time
 import warnings
 from typing import Sequence
 
@@ -111,7 +111,6 @@ from repro.shuffle.api import (ShuffleReport, require,
 # Backwards-compatible re-exports: this machinery moved to the shuffle
 # library (shuffle/runtime.py) when the generic dataflow API was carved
 # out; the old names keep working for existing callers.
-Span = _rt.Span
 PhaseTimeline = _rt.PhaseTimeline
 JobControl = _rt.JobControl
 AdaptiveBudgetGovernor = _rt.AdaptiveBudgetGovernor
@@ -285,6 +284,11 @@ def _contiguous_id_base(ids: np.ndarray) -> int | None:
     return base
 
 
+def _untimed(phase: str, worker: str = ""):
+    """PhaseTimeline.span's stand-in where nothing records spans."""
+    return contextlib.nullcontext()
+
+
 def _validate_plan(plan: ExternalSortPlan, w: int) -> None:
     """Plan validation shared by every sort entry point, including the
     mesh-dependent divisibility check. ValueError, not assert: must
@@ -332,12 +336,12 @@ class WaveSorter:
             boundaries=(None if boundaries is None
                         else tuple(int(b) for b in np.asarray(boundaries))),
         )
-        self._sort = jax.jit(
-            lambda k, i: streaming_sort(
-                k, i, mesh=mesh, axis_names=axis_names,
-                num_rounds=plan.num_rounds, cfg=self.cfg,
-            )
-        )
+        def wave_sort(k, i):
+            return streaming_sort(k, i, mesh=mesh, axis_names=axis_names,
+                                  num_rounds=plan.num_rounds, cfg=self.cfg)
+
+        # A named function, so the device trace's module is jit_wave_sort.
+        self._sort = jax.jit(wave_sort)
         self._local_bounds = (
             np.asarray(self.cfg.keyspace.local_reducer_boundaries())
             if self.r1 > 1 else None
@@ -373,32 +377,38 @@ class WaveSorter:
         """Stage 1 of the map body: the mesh sort (serialized on the
         device lock), returned as host copies (sk, si, vcounts).
 
-        With a timeline, the interval is recorded under BOTH
-        map.device_sort (the per-stage span, docs/OBSERVABILITY.md) and
-        map.compute (the long-standing device-time total every report
-        and test reads).
+        With a timeline, the call is one map.device_sort span holding,
+        in order, map.device_lock (the acquire), map.h2d (keys and ids
+        to the device, waited for), map.sort (the jitted sort, waited
+        for) and map.d2h (the sorted run back to the host).
         """
-        t = time.perf_counter()
-        with self._device_lock:
-            sk, si, vcounts, ovf = self._sort(jnp.asarray(keys),
-                                              jnp.asarray(ids))
-            sk, si, vcounts = (np.asarray(sk), np.asarray(si),
-                               np.asarray(vcounts))
-        if bool(np.asarray(ovf)):
-            raise RuntimeError(
-                "shuffle block overflow — raise capacity_factor"
-            )
-        if timeline is not None:
-            timeline.add("map.device_sort", t, worker=tag)
-            timeline.add("map.compute", t, worker=tag)
+        span = timeline.span if timeline is not None else _untimed
+        with span("map.device_sort", worker=tag):
+            with span("map.device_lock", worker=tag):
+                self._device_lock.acquire()
+            try:
+                with span("map.h2d", worker=tag):
+                    dk, di = jax.block_until_ready(
+                        (jnp.asarray(keys), jnp.asarray(ids)))
+                with span("map.sort", worker=tag):
+                    sk, si, vcounts, ovf = jax.block_until_ready(
+                        self._sort(dk, di))
+                with span("map.d2h", worker=tag):
+                    sk, si, vcounts = (np.asarray(sk), np.asarray(si),
+                                       np.asarray(vcounts))
+            finally:
+                self._device_lock.release()
+            if bool(np.asarray(ovf)):
+                raise RuntimeError(
+                    "shuffle block overflow — raise capacity_factor"
+                )
         return sk, si, vcounts
 
     def encode_and_spill(self, store: StoreBackend, bucket: str, g: int,
                          sk, si, vcounts, ids, payload, *,
                          spiller: staging.AsyncWriter,
                          timeline: PhaseTimeline, tag: str,
-                         offsets_out: dict, span: str = "map.encode",
-                         t0: float | None = None) -> None:
+                         offsets_out: dict, span: str = "map.encode") -> None:
         """Stage 2 of the map body: slice each mesh worker's run out of
         the sorted wave, gather payload rows, encode, and spill.
 
@@ -407,53 +417,56 @@ class WaveSorter:
         object's manifest metadata, so a process-backed worker could
         recover them from the store alone). `span` names the recorded
         compute segments — "map.encode" as a pipeline stage,
-        "map.compute" from the monolithic compute_and_spill.
+        "map.compute" from the monolithic compute_and_spill — which
+        leave out each run's map.spill_wait. Inside them, map.gather
+        times the id -> row lookup and the payload row gather, and
+        map.pack the record encode.
         """
         plan, w, pw = self.plan, self.w, self.pw
-        t_comp = time.perf_counter() if t0 is None else t0
-        # id -> wave row for gathering payload of shuffled records:
-        # O(1) offset arithmetic when the wave's ids are contiguous
-        # (the gensort layout), argsort gather otherwise.
-        id_base = _contiguous_id_base(ids) if pw else None
-        order = sorted_ids = None
-        if pw and id_base is None:
-            order = np.argsort(ids)
-            sorted_ids = ids[order]
+        with timeline.span(span, worker=tag), \
+                timeline.span("map.gather", worker=tag):
+            # id -> wave row for gathering payload of shuffled records:
+            # O(1) offset arithmetic when the wave's ids are contiguous
+            # (the gensort layout), argsort gather otherwise.
+            id_base = _contiguous_id_base(ids) if pw else None
+            order = sorted_ids = None
+            if pw and id_base is None:
+                order = np.argsort(ids)
+                sorted_ids = ids[order]
         seg = sk.shape[0] // w
         for wid in range(w):
-            n = int(vcounts[wid])
-            run_k = sk[wid * seg : wid * seg + n]
-            run_i = si[wid * seg : wid * seg + n]
-            run_p = None
-            if pw:
-                if id_base is not None:
-                    sel = run_i.astype(np.int64) - id_base
+            with timeline.span(span, worker=tag):
+                n = int(vcounts[wid])
+                run_k = sk[wid * seg : wid * seg + n]
+                run_i = si[wid * seg : wid * seg + n]
+                run_p = None
+                if pw:
+                    with timeline.span("map.gather", worker=tag):
+                        if id_base is not None:
+                            sel = run_i.astype(np.int64) - id_base
+                        else:
+                            sel = order[np.searchsorted(sorted_ids, run_i)]
+                        run_p = payload[sel]
+                if self._local_bounds is not None:
+                    internal = np.searchsorted(
+                        run_k, self._local_bounds[wid], side="left")
                 else:
-                    sel = order[np.searchsorted(sorted_ids, run_i)]
-                run_p = payload[sel]
-            if self._local_bounds is not None:
-                internal = np.searchsorted(
-                    run_k, self._local_bounds[wid], side="left")
-            else:
-                internal = np.empty((0,), np.int64)
-            offsets = np.concatenate(([0], internal, [n])).astype(np.int64)
-            offsets_out[(g, wid)] = offsets
-            data = rec.encode_records(run_k, run_i, run_p)
+                    internal = np.empty((0,), np.int64)
+                offsets = np.concatenate(([0], internal, [n])).astype(np.int64)
+                offsets_out[(g, wid)] = offsets
+                with timeline.span("map.pack", worker=tag):
+                    data = rec.encode_records(run_k, run_i, run_p)
             # Submit each encoded run immediately: the AsyncWriter
             # backpressure bound (at most max_inflight encoded runs
             # in host memory) only holds if we never batch them.
-            timeline.add(span, t_comp, worker=tag)
-            t_spill = time.perf_counter()
-            spiller.submit(_timed_spill, timeline, tag, store, bucket,
-                           _spill_key(plan, g, wid), data, {
-                               "records": n,
-                               "wave": g,
-                               "worker": wid,
-                               "reducer_offsets": [int(o) for o in offsets],
-                           })
-            timeline.add("map.spill_wait", t_spill, worker=tag)
-            t_comp = time.perf_counter()
-        timeline.add(span, t_comp, worker=tag)
+            with timeline.span("map.spill_wait", worker=tag):
+                spiller.submit(_timed_spill, timeline, tag, store, bucket,
+                               _spill_key(plan, g, wid), data, {
+                                   "records": n,
+                                   "wave": g,
+                                   "worker": wid,
+                                   "reducer_offsets": [int(o) for o in offsets],
+                               })
 
     def compute_and_spill(self, store: StoreBackend, bucket: str, g: int,
                           keys, ids, payload, *, spiller: staging.AsyncWriter,
@@ -463,12 +476,12 @@ class WaveSorter:
         the monolithic (non-pipelined) map body: device_sort +
         encode_and_spill back to back on the calling thread, with the
         original map.compute/map.spill_wait span structure."""
-        t_comp = time.perf_counter()
-        sk, si, vcounts = self.device_sort(keys, ids)
+        with timeline.span("map.compute", worker=tag):
+            sk, si, vcounts = self.device_sort(keys, ids)
         self.encode_and_spill(store, bucket, g, sk, si, vcounts, ids,
                               payload, spiller=spiller, timeline=timeline,
                               tag=tag, offsets_out=offsets_out,
-                              span="map.compute", t0=t_comp)
+                              span="map.compute")
 
 
 def external_sort(

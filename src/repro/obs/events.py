@@ -2,9 +2,10 @@
 
 Events are flat structured dicts on one monotonic clock (the Tracer's
 perf_counter origin), so a reduce task's merge span and the ranged GETs
-it issued sort onto one timeline. The log is bounded and drop-counting
-like shuffle/runtime.PhaseTimeline: a huge run cannot hoard memory, and
-the export records how much was dropped instead of silently truncating.
+it issued sort onto one timeline. The log is bounded and drop-counting:
+a huge run cannot hoard memory, and the export records how much was
+dropped instead of silently truncating. It is the one place individual
+spans are kept (shuffle/runtime.PhaseTimeline keeps only totals).
 
 Event schema (every exporter consumes exactly this):
 
@@ -29,9 +30,9 @@ from repro.obs.metrics import MetricsRegistry
 class EventLog:
     """Bounded, thread-safe, append-only event buffer.
 
-    Keeps the first `max_events` events (the PhaseTimeline convention:
-    oldest kept, so the job's structure survives even when a long tail
-    of store events overflows) and counts the rest in `dropped`.
+    Keeps the first `max_events` events (oldest kept, so the job's
+    structure survives even when a long tail of store events overflows)
+    and counts the rest in `dropped`.
     """
 
     def __init__(self, max_events: int = 65536):
@@ -97,18 +98,21 @@ class Tracer:
 
     # -- timeline bridge ---------------------------------------------------
 
-    def timeline_sink(self) -> Callable[[str, float, float, str], None]:
+    def timeline_sink(self) -> Callable[[str, float, float, str, dict],
+                                        None]:
         """A PhaseTimeline `sink`: forwards every recorded span as an
-        event, deriving attribution from the timeline's tag convention
-        ("w0/g3" = worker w0, map task g3; a bare "r12" is the
-        single-host driver, worker "host")."""
+        event with the span's attrs (`cpu_s` on a timed block),
+        deriving attribution from the timeline's tag convention ("w0/g3"
+        = worker w0, map task g3; a bare "r12" is the single-host
+        driver, worker "host")."""
 
-        def sink(phase: str, start: float, end: float, tag: str) -> None:
+        def sink(phase: str, start: float, end: float, tag: str,
+                 attrs: dict) -> None:
             worker, _, task = tag.rpartition("/")
             ctx = TraceContext(
                 job=self.job, phase=phase.split(".", 1)[0],
                 task=task or None, worker=worker or "host")
-            self.event(phase, start, end, ctx=ctx)
+            self.event(phase, start, end, ctx=ctx, **attrs)
 
         return sink
 

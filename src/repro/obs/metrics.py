@@ -139,8 +139,8 @@ def render_report(report) -> list[str]:
             f"in {rep.reduce_seconds:.2f}s wall = "
             f"{reduce_busy / rep.reduce_seconds:.2f}x overlap")
     if rep.spans_dropped:
-        lines.append(f"[spans] {rep.spans_dropped} spans beyond the "
-                     "recorder cap were dropped (totals stay exact)")
+        lines.append(f"[spans] {rep.spans_dropped} events beyond the "
+                     "event log's cap were dropped (totals stay exact)")
 
     for tier, s in (rep.tier_stats or {}).items():
         lines.append(

@@ -44,7 +44,7 @@ from repro.io.backends import StoreBackend, StoreStats
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.io.staging import AsyncWriter
-    from repro.shuffle.runtime import PhaseTimeline, Span
+    from repro.shuffle.runtime import PhaseTimeline
 
 
 def require(condition: bool, knob: str, value, why: str) -> None:
@@ -223,8 +223,7 @@ class MapOp(abc.ABC):
     #   device_step(task, data, *, timeline, tag) -> staged
     #       The device-bound portion (sort/compute). Runs on a dedicated
     #       single-thread stage; must not touch the store. Records
-    #       map.device_sort (and map.compute, for phase-total
-    #       compatibility) spans.
+    #       map.device_sort spans.
     #
     #   encode_step(store, bucket, task, staged, *, spiller, timeline,
     #               tag) -> None
@@ -368,8 +367,7 @@ class ShuffleReport:
     parallel_reducers: int = 1  # concurrent merges the scheduler(s) ran
     reduce_memory_budget_bytes: int = 0  # the global governor (0 = none)
     tier_stats: dict[str, StoreStats] | None = None  # per-tier deltas
-    spans: list["Span"] = dataclasses.field(default_factory=list)
-    spans_dropped: int = 0  # spans beyond the recorder cap (totals stay exact)
+    spans_dropped: int = 0  # events past the Tracer's EventLog cap (totals stay exact)
     phase_seconds: dict[str, float] = dataclasses.field(default_factory=dict)
     metrics: dict = dataclasses.field(default_factory=dict)  # MetricsRegistry.snapshot()
 
@@ -448,8 +446,8 @@ class ClusterShuffleReport:
 
     @property
     def spans_dropped(self) -> int:
-        """Spans beyond the recorder cap (see runtime.PhaseTimeline
-        `max_spans`); phase totals stay exact regardless."""
+        """Events past the cap of the job Tracer's EventLog
+        (`max_events`); phase totals stay exact regardless."""
         return self.report.spans_dropped
 
     @property

@@ -40,7 +40,6 @@ any worker count, under worker kills, and with the combiner on or off
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -136,38 +135,38 @@ class GroupByMapOp(MapOp):
     def process(self, store: StoreBackend, bucket: str, task: int, data, *,
                 spiller, timeline, tag) -> None:
         keys, _ids, payload = data  # raw ids die at normalization below
-        t_comp = time.perf_counter()
-        parts = self.partitioner.partition_of(keys)
-        order = np.lexsort((keys, parts))
-        sk = np.ascontiguousarray(keys[order])
-        svals = np.ascontiguousarray(payload[order])
-        # Normalize to (key, count, sum): every raw record contributes
-        # count 1; the combiner (if any) then collapses equal keys so
-        # repeated keys cost one spilled record, not many.
-        scounts = np.ones(sk.shape, np.uint32)
-        sparts = parts[order]  # already routed — don't re-hash the split
-        if self.combiner is not None:
-            n_before = sk.shape[0]
-            sk, scounts, svals = self.combiner.combine(sk, scounts, svals)
-            if sk.shape[0] != n_before:
-                # A pluggable combiner only promises collapsed records,
-                # not index correspondence — re-route the (much smaller)
-                # collapsed span.
-                sparts = self.partitioner.partition_of(sk)
-        offsets = np.searchsorted(
-            sparts, np.arange(self.partitioner.num_partitions + 1),
-            side="left").astype(np.int64)
-        self.partition_offsets[task] = offsets
-        encoded = rec.encode_records(sk, scounts, svals)
-        timeline.add("map.compute", t_comp, worker=tag)
-        t_spill = time.perf_counter()
-        spiller.submit(timed_put, timeline, tag, store, bucket,
-                       self.run_key(task), encoded, {
-                           "records": int(sk.shape[0]),
-                           "task": task,
-                           "partition_offsets": [int(o) for o in offsets],
-                       })
-        timeline.add("map.spill_wait", t_spill, worker=tag)
+        with timeline.span("map.compute", worker=tag):
+            parts = self.partitioner.partition_of(keys)
+            order = np.lexsort((keys, parts))
+            sk = np.ascontiguousarray(keys[order])
+            svals = np.ascontiguousarray(payload[order])
+            # Normalize to (key, count, sum): every raw record
+            # contributes count 1; the combiner (if any) then collapses
+            # equal keys so repeated keys cost one spilled record, not
+            # many.
+            scounts = np.ones(sk.shape, np.uint32)
+            sparts = parts[order]  # already routed — don't re-hash
+            if self.combiner is not None:
+                n_before = sk.shape[0]
+                sk, scounts, svals = self.combiner.combine(sk, scounts,
+                                                           svals)
+                if sk.shape[0] != n_before:
+                    # A pluggable combiner only promises collapsed
+                    # records, not index correspondence — re-route the
+                    # (much smaller) collapsed span.
+                    sparts = self.partitioner.partition_of(sk)
+            offsets = np.searchsorted(
+                sparts, np.arange(self.partitioner.num_partitions + 1),
+                side="left").astype(np.int64)
+            self.partition_offsets[task] = offsets
+            encoded = rec.encode_records(sk, scounts, svals)
+        with timeline.span("map.spill_wait", worker=tag):
+            spiller.submit(timed_put, timeline, tag, store, bucket,
+                           self.run_key(task), encoded, {
+                               "records": int(sk.shape[0]),
+                               "task": task,
+                               "partition_offsets": [int(o) for o in offsets],
+                           })
 
 
 class _GroupAggSink(PartitionReducer):

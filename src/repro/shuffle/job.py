@@ -211,8 +211,7 @@ class ShuffleSession:
         # into the tracer (absolute times; the tracer normalises to its
         # own origin), so the Chrome trace sees exactly what the report
         # sees.
-        self.timeline = rt.PhaseTimeline(origin=time.perf_counter(),
-                                         sink=self.tracer.timeline_sink())
+        self.timeline = rt.PhaseTimeline(sink=self.tracer.timeline_sink())
         self.control = rt.JobControl()
         self.peak = rt.PeakTracker()
         self.shared = rt.ReduceShared(
@@ -385,8 +384,7 @@ class ShuffleSession:
             parallel_reducers=self.slots,
             reduce_memory_budget_bytes=plan.reduce_memory_budget_bytes,
             tier_stats=tier_stats,
-            spans=self.timeline.spans(),
-            spans_dropped=self.timeline.dropped,
+            spans_dropped=self.tracer.log.dropped,
             phase_seconds=self.timeline.totals(),
             metrics=reg.snapshot(),
         )

@@ -57,7 +57,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Sequence
 
 import numpy as np
@@ -278,30 +277,29 @@ class SubrangeSortMapOp(MapOp):
     def process(self, store: StoreBackend, bucket: str, task: int, data, *,
                 spiller, timeline, tag) -> None:
         keys, ids, payload = data
-        t0 = time.perf_counter()
-        k64 = (keys.astype(np.uint64) << np.uint64(32)) | ids.astype(
-            np.uint64)
-        order = np.argsort(k64, kind="stable")
-        sk, si = keys[order], ids[order]
-        sp = None if payload is None else payload[order]
-        # routed is monotone in k64, so it is ascending over the sorted
-        # run: offsets[j] = #{routed < bound_j}, the device kernel's
-        # exact contract (kernels/range_partition).
-        routed = self.partitioner.key_route.routed(sk, si)
-        internal = np.searchsorted(routed, self.partitioner.boundaries(),
-                                   side="left")
-        offsets = np.concatenate(([0], internal, [sk.size])).astype(np.int64)
-        self.spill_offsets[(task, 0)] = offsets
-        encoded = rec.encode_records(sk, si, sp)
-        timeline.add("map.compute", t0, worker=tag)
-        t_spill = time.perf_counter()
-        spiller.submit(_rt.timed_put, timeline, tag, store, bucket,
-                       self.spill_keys(task)[0], encoded, {
-                           "records": int(sk.size),
-                           "task": task,
-                           "reducer_offsets": [int(o) for o in offsets],
-                       })
-        timeline.add("map.spill_wait", t_spill, worker=tag)
+        with timeline.span("map.compute", worker=tag):
+            k64 = (keys.astype(np.uint64) << np.uint64(32)) | ids.astype(
+                np.uint64)
+            order = np.argsort(k64, kind="stable")
+            sk, si = keys[order], ids[order]
+            sp = None if payload is None else payload[order]
+            # routed is monotone in k64, so it is ascending over the
+            # sorted run: offsets[j] = #{routed < bound_j}, the device
+            # kernel's exact contract (kernels/range_partition).
+            routed = self.partitioner.key_route.routed(sk, si)
+            internal = np.searchsorted(routed, self.partitioner.boundaries(),
+                                       side="left")
+            offsets = np.concatenate(([0], internal, [sk.size])).astype(
+                np.int64)
+            self.spill_offsets[(task, 0)] = offsets
+            encoded = rec.encode_records(sk, si, sp)
+        with timeline.span("map.spill_wait", worker=tag):
+            spiller.submit(_rt.timed_put, timeline, tag, store, bucket,
+                           self.spill_keys(task)[0], encoded, {
+                               "records": int(sk.size),
+                               "task": task,
+                               "reducer_offsets": [int(o) for o in offsets],
+                           })
 
 
 class SubrangeMergeReduceOp(ReduceOp):

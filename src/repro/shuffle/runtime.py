@@ -18,16 +18,17 @@ global `reduce_memory_budget_bytes` — see the governor docstring for
 the provable bound. Encoded output parts being sliced/uploaded sit on
 top (~(1 + max_inflight_writes) x part bytes per active reducer).
 
-Observability: PhaseTimeline's raw span list is capped at `max_spans`
-(default 4096, a constructor knob) — per-phase totals stay exact past
-the cap and the report's `spans_dropped` (surfaced by ShuffleReport and
-ClusterShuffleReport alike) counts the overflow, so a huge run degrades
-to aggregates instead of hoarding memory. Wire a `sink` (usually
-obs/events.Tracer.timeline_sink()) to forward every span into the
-unified event log as it is recorded; task execution binds an
-obs TraceContext (phase/task/worker) around each map task and reduce
-partition so store requests issued on behalf of a task — including
-writes handed to staging pools — are attributed to it.
+Observability: PhaseTimeline keeps exact per-phase totals and forwards
+every span to its `sink` (usually obs/events.Tracer.timeline_sink()),
+whose bounded EventLog is the one place individual spans are kept.
+`PhaseTimeline.span` is how a stage is timed: it records the thread's
+CPU seconds beside the wall interval (`cpu_s`) and opens a
+`jax.profiler.TraceAnnotation` of the same name, so a profile taken
+during a job shows each span on its host thread, on the device trace's
+clock. Task execution binds an obs TraceContext (phase/task/worker)
+around each map task and reduce partition so store requests issued on
+behalf of a task — including writes handed to staging pools — are
+attributed to it.
 """
 from __future__ import annotations
 
@@ -64,70 +65,64 @@ def _task_context(phase: str, task, tag_prefix: str) -> TraceContext:
     return base.with_phase(phase).with_task(task).with_worker(worker)
 
 
-@dataclasses.dataclass(frozen=True)
-class Span:
-    """One recorded phase interval, seconds relative to the job start."""
-
-    phase: str  # e.g. "map.compute", "reduce.upload"
-    start: float
-    end: float
-    worker: str = ""  # "w3" map task / "r12" reducer tag
-
-    @property
-    def seconds(self) -> float:
-        return self.end - self.start
-
-
 class PhaseTimeline:
     """Thread-safe span recorder for the per-phase timeline.
 
-    Aggregate per-phase totals are exact; the raw span list is capped at
-    `max_spans` (oldest kept) so a huge run cannot hoard memory — the
-    report's `spans_dropped` says how many were dropped. Because spans from overlapping
-    threads both count wall time, a phase total larger than the enclosing
+    Per-phase totals are exact. Because spans from overlapping threads
+    both count wall time, a phase total larger than the enclosing
     stage's wall time is *measured overlap*, which is the point.
     """
 
-    def __init__(self, origin: float, *, max_spans: int = 4096,
-                 sink=None):
-        self._origin = origin
+    def __init__(self, *, sink=None):
         self._lock = threading.Lock()
         self._totals: dict[str, float] = {}
-        self._spans: list[Span] = []
-        self._max = int(max_spans)
-        self._sink = sink  # callable(phase, abs_start, abs_end, worker_tag)
-        self.dropped = 0
+        # callable(phase, abs_start, abs_end, worker_tag, attrs)
+        self._sink = sink
+        self._annotation = None  # jax.profiler.TraceAnnotation, resolved late
 
     def add(self, phase: str, start: float, end: float | None = None,
-            *, worker: str = "") -> None:
+            *, worker: str = "", **attrs) -> None:
+        """Record [start, end) (perf_counter readings) after the fact —
+        for a span that cannot be a `with` block; `attrs` ride along to
+        the sink."""
         end = time.perf_counter() if end is None else end
-        span = Span(phase, start - self._origin, end - self._origin, worker)
         with self._lock:
-            self._totals[phase] = self._totals.get(phase, 0.0) + span.seconds
-            if len(self._spans) < self._max:
-                self._spans.append(span)
-            else:
-                self.dropped += 1
+            self._totals[phase] = self._totals.get(phase, 0.0) + (end - start)
         if self._sink is not None:
             # Outside the lock: the sink (obs Tracer) has its own, and
             # it receives ABSOLUTE times — its clock origin may differ.
-            self._sink(phase, start, end, worker)
+            self._sink(phase, start, end, worker, attrs)
+
+    def _annotate(self, phase: str):
+        if self._annotation is None:
+            try:
+                from jax.profiler import TraceAnnotation
+            except ImportError:  # no jax in this process: plain spans
+                TraceAnnotation = lambda name: contextlib.nullcontext()  # noqa: E731
+            self._annotation = TraceAnnotation
+        return self._annotation(phase)
 
     @contextlib.contextmanager
     def span(self, phase: str, worker: str = ""):
-        t = time.perf_counter()
-        try:
+        """Time the with-block as one `phase` span on this thread.
+
+        The span carries `cpu_s`, the thread's CPU seconds inside the
+        block (its wall time less `cpu_s` is time off the CPU: the GIL,
+        locks, I/O, the scheduler), and shows in a profiler trace as a
+        TraceAnnotation. A block that raises records nothing: a failed
+        or abandoned attempt is not the stage's work.
+        """
+        with self._annotate(phase):
+            t = time.perf_counter()
+            cpu = time.thread_time()  # read inside the wall interval
             yield
-        finally:
-            self.add(phase, t, worker=worker)
+            cpu = time.thread_time() - cpu
+            end = time.perf_counter()
+        self.add(phase, t, end, worker=worker, cpu_s=cpu)
 
     def totals(self) -> dict[str, float]:
         with self._lock:
             return dict(self._totals)
-
-    def spans(self) -> list[Span]:
-        with self._lock:
-            return list(self._spans)
 
 
 class PeakTracker:
@@ -511,17 +506,15 @@ class _AbandonGatedReads:
 def timed_part(timeline: PhaseTimeline, tag: str, mp, index: int,
                data: bytes) -> None:
     """Background part upload, recorded as a reduce.upload span."""
-    t = time.perf_counter()
-    mp.put_part(index, data)
-    timeline.add("reduce.upload", t, worker=tag)
+    with timeline.span("reduce.upload", worker=tag):
+        mp.put_part(index, data)
 
 
 def timed_put(timeline: PhaseTimeline, tag: str, store, bucket: str,
               key: str, data: bytes, metadata: dict) -> None:
     """Background spill put, recorded as a map.spill span."""
-    t = time.perf_counter()
-    store.put(bucket, key, data, metadata=metadata)
-    timeline.add("map.spill", t, worker=tag)
+    with timeline.span("map.spill", worker=tag):
+        store.put(bucket, key, data, metadata=metadata)
 
 
 def finalize_session(timeline: PhaseTimeline, tag: str,
@@ -542,22 +535,20 @@ def finalize_session(timeline: PhaseTimeline, tag: str,
     all parts land and immediately before the commit, a False answer
     (another attempt of this task already committed durably) aborts the
     session instead — no double commit, no on_done."""
-    t = time.perf_counter()
-    try:
-        uploader.close()  # waits all parts; re-raises the first failure
-    except BaseException:
-        mp.abort()
-        raise
-    try:
-        if commit_gate is not None and not commit_gate():
+    with timeline.span("reduce.upload_wait", worker=tag):
+        try:
+            uploader.close()  # waits all parts; re-raises the first failure
+        except BaseException:
             mp.abort()
-            return
-        mp.complete()
-    except BaseException:
-        mp.abort()
-        raise
-    finally:
-        timeline.add("reduce.upload_wait", t, worker=tag)
+            raise
+        try:
+            if commit_gate is not None and not commit_gate():
+                mp.abort()
+                return
+            mp.complete()
+        except BaseException:
+            mp.abort()
+            raise
     if on_done is not None:
         on_done()
 
@@ -712,7 +703,11 @@ class ReduceScheduler:
         # ranged GETs (inline or via the refill pool), part uploads and
         # the finisher commit (captured by AsyncWriter.submit) are all
         # attributed to reduce task r on this worker.
-        with use_context(_task_context("reduce", f"r{r}", self.tag_prefix)):
+        # reduce.partition spans this attempt's hold on the scheduler
+        # slot, from pick-up to the hand-off to the finisher queue.
+        with use_context(_task_context("reduce", f"r{r}", self.tag_prefix)), \
+                self.shared.timeline.span("reduce.partition",
+                                          worker=f"{self.tag_prefix}r{r}"):
             self._reduce_one_inner(r, refill_pool, finishers, on_done)
 
     def _reduce_one_inner(self, r: int, refill_pool, finishers,
@@ -762,9 +757,9 @@ class ReduceScheduler:
         def submit_part(data: bytes) -> None:
             nonlocal next_part
             idx, next_part = next_part, next_part + 1
-            t = time.perf_counter()  # blocks under upload backpressure
-            uploader.submit(timed_part, timeline, tag, mp, idx, data)
-            timeline.add("reduce.upload_wait", t, worker=tag)
+            # submit blocks under upload backpressure
+            with timeline.span("reduce.upload_wait", worker=tag):
+                uploader.submit(timed_part, timeline, tag, mp, idx, data)
 
         try:
             cursors = [
@@ -812,17 +807,15 @@ class ReduceScheduler:
                                 chunk_records = grown
                                 c.set_chunk(grown)
                         if c.k64.size == 0 and c.has_more_remote:
-                            t = time.perf_counter()
-                            c.refill()
-                            timeline.add("reduce.fetch", t, worker=tag)
+                            with timeline.span("reduce.fetch", worker=tag):
+                                c.refill()
                         shared.peak.update(akey, c.buffered_bytes)
-                        t = time.perf_counter()
-                        frag = c.take_upto(None)
-                        done = ci == len(cursors) - 1 and c.exhausted
-                        body = sink.consume([frag], final=done)
-                        if body:
-                            outbuf += body
-                        timeline.add("reduce.merge", t, worker=tag)
+                        with timeline.span("reduce.merge", worker=tag):
+                            frag = c.take_upto(None)
+                            done = ci == len(cursors) - 1 and c.exhausted
+                            body = sink.consume([frag], final=done)
+                            if body:
+                                outbuf += body
                         while len(outbuf) >= part_bytes:
                             submit_part(bytes(outbuf[:part_bytes]))
                             del outbuf[:part_bytes]
@@ -846,42 +839,41 @@ class ReduceScheduler:
                 need = [c for c in cursors
                         if c.k64.size == 0 and c.has_more_remote]
                 if need:
-                    t = time.perf_counter()
-                    if len(need) == 1:
-                        need[0].refill()
-                    else:  # concurrent ranged GETs: one RTT per cycle
-                        # bind_context: the shared refill pool's threads
-                        # must issue these GETs as THIS partition's.
-                        list(refill_pool.map(bind_context(RunCursor.refill),
-                                             need))
-                    timeline.add("reduce.fetch", t, worker=tag)
+                    with timeline.span("reduce.fetch", worker=tag):
+                        if len(need) == 1:
+                            need[0].refill()
+                        else:  # concurrent ranged GETs: one RTT per cycle
+                            # bind_context: the shared refill pool's
+                            # threads must issue these GETs as THIS
+                            # partition's.
+                            list(refill_pool.map(
+                                bind_context(RunCursor.refill), need))
                 shared.peak.update(akey,
                                    sum(c.buffered_bytes for c in cursors))
-                t = time.perf_counter()
-                # Safe emit bound: the smallest last-buffered key among
-                # runs that still have un-fetched records — nothing
-                # later can sort below it. When no run has remote data
-                # left, everything buffered is emittable (and this is
-                # guaranteed to be the final cycle: any cursor with
-                # remote data would survive the exhausted filter).
-                remote_tails = [c.k64[-1] for c in cursors
-                                if c.has_more_remote]
-                bound = min(remote_tails) if remote_tails else None
-                frags = [c.take_upto(bound) for c in cursors]
-                cursors = [c for c in cursors if not c.exhausted]
-                body = sink.consume(frags, final=bound is None)
-                if body:
-                    outbuf += body
-                timeline.add("reduce.merge", t, worker=tag)
+                with timeline.span("reduce.merge", worker=tag):
+                    # Safe emit bound: the smallest last-buffered key
+                    # among runs that still have un-fetched records —
+                    # nothing later can sort below it. When no run has
+                    # remote data left, everything buffered is emittable
+                    # (and this is guaranteed to be the final cycle: any
+                    # cursor with remote data would survive the
+                    # exhausted filter).
+                    remote_tails = [c.k64[-1] for c in cursors
+                                    if c.has_more_remote]
+                    bound = min(remote_tails) if remote_tails else None
+                    frags = [c.take_upto(bound) for c in cursors]
+                    cursors = [c for c in cursors if not c.exhausted]
+                    body = sink.consume(frags, final=bound is None)
+                    if body:
+                        outbuf += body
                 while len(outbuf) >= part_bytes:
                     submit_part(bytes(outbuf[:part_bytes]))
                     del outbuf[:part_bytes]
             # finalize can block on real merge work (the device sink's
             # in-flight window) — record it under reduce.merge so the
             # span is the COMPLETE scheduler-visible merge cost.
-            t = time.perf_counter()
-            tail, part0 = sink.finalize()
-            timeline.add("reduce.merge", t, worker=tag)
+            with timeline.span("reduce.merge", worker=tag):
+                tail, part0 = sink.finalize()
             if tail:
                 outbuf += tail
                 while len(outbuf) >= part_bytes:
@@ -893,9 +885,8 @@ class ReduceScheduler:
             if outbuf or (next_part == first_part and part0 is None):
                 submit_part(bytes(outbuf))
             if part0 is not None:
-                t = time.perf_counter()
-                uploader.submit(timed_part, timeline, tag, mp, 0, part0)
-                timeline.add("reduce.upload_wait", t, worker=tag)
+                with timeline.span("reduce.upload_wait", worker=tag):
+                    uploader.submit(timed_part, timeline, tag, mp, 0, part0)
         except BaseException:
             # Setup, merge, or upload died mid-session: let in-flight
             # parts settle, then discard the session — never commit it.
@@ -1001,13 +992,12 @@ def run_map_tasks(store: StoreBackend, bucket: str, map_op: MapOp,
                                             lambda g=g: commit_gate(g)))
             if pipelined:
                 def load_one(g=g, view=view):
-                    t = time.perf_counter()
-                    try:
-                        data = map_op.load(view, bucket, g)
+                    try:  # a lost load records no map.decode span
+                        with timeline.span("map.decode",
+                                           worker=f"{tag_prefix}g{g}"):
+                            return map_op.load(view, bucket, g)
                     except AttemptLost:
                         return _LOST
-                    timeline.add("map.decode", t, worker=f"{tag_prefix}g{g}")
-                    return data
                 yield bind_context(load_one, ctx)
             else:
                 def load_one(g=g, view=view):
@@ -1123,7 +1113,6 @@ __all__ = [
     "ReduceShared",
     "RunCursor",
     "SiblingFailed",
-    "Span",
     "finalize_session",
     "merge_fragments",
     "reduce_chunking",

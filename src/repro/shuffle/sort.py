@@ -18,7 +18,7 @@ so they can never drift.
 """
 from __future__ import annotations
 
-import time
+import contextlib
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
@@ -166,13 +166,12 @@ class _DeviceMergeSink(PartitionReducer):
     def _merge_encode(self, frags) -> bytes:
         from repro.kernels.kway_merge import merge_fragments_device
 
-        t = time.perf_counter()
-        mk, mi, mp = merge_fragments_device(frags, self._pw,
-                                            impl=self._impl)
-        body = rec.encode_body(mk, mi, mp) if mk.size else b""
-        if self._timeline is not None:
-            self._timeline.add("reduce.device_merge", t, worker=self._tag)
-        return body
+        span = (contextlib.nullcontext() if self._timeline is None else
+                self._timeline.span("reduce.device_merge", worker=self._tag))
+        with span:
+            mk, mi, mp = merge_fragments_device(frags, self._pw,
+                                                impl=self._impl)
+            return rec.encode_body(mk, mi, mp) if mk.size else b""
 
     def consume(self, frags, *, final: bool) -> bytes:
         job = self._pool.submit(self._merge_encode, frags)

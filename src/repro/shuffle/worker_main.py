@@ -48,7 +48,6 @@ import os
 import queue
 import sys
 import threading
-import time
 import traceback
 
 
@@ -229,7 +228,7 @@ def main() -> int:
 
     def run_phase(phase: str, gated: bool) -> None:
         control = rt.JobControl()
-        timeline = rt.PhaseTimeline(origin=time.perf_counter())
+        timeline = rt.PhaseTimeline()
         if phase == "map":
             # `gated` means the parent runs a speculation claim pool for
             # this phase: poll the commit RPC per fetched map chunk (and

@@ -185,7 +185,8 @@ print("OK")
 
 def test_map_pipeline_byte_identical_and_staged_spans():
     # The pipelined map executor (default-on) must not change a byte vs
-    # the monolithic path, and must surface the staged spans. The
+    # the monolithic path, and must surface the staged spans, each
+    # recorded once (no map.compute mirror of map.device_sort). The
     # monolithic path keeps its original span shape (map.compute, no
     # map.decode/device_sort/encode).
     run_with_devices(SETUP + """
@@ -199,13 +200,15 @@ for k in ("map.decode", "map.device_sort", "map.encode"):
 rep_pipe = run(plan, workers=0)
 assert layout() == want, "map_pipeline changed output bytes"
 ps = rep_pipe.phase_seconds
-for k in ("map.decode", "map.device_sort", "map.encode", "map.compute",
-          "map.spill"):
+for k in ("map.decode", "map.device_sort", "map.encode", "map.spill",
+          "map.gather", "map.pack", "map.device_lock", "map.h2d",
+          "map.sort", "map.d2h"):
     assert ps.get(k, 0) > 0, (k, ps)
-# device_sort is recorded under map.compute too (phase-total compat):
-# the same interval, re-stamped — so equal up to the add() overhead.
-assert ps["map.compute"] >= ps["map.device_sort"], ps
-assert ps["map.compute"] - ps["map.device_sort"] < 0.01, ps
+assert "map.compute" not in ps, ps
+# The stage spans split their stage's time, never add to it.
+assert ps["map.gather"] + ps["map.pack"] <= ps["map.encode"], ps
+assert (ps["map.device_lock"] + ps["map.h2d"] + ps["map.sort"]
+        + ps["map.d2h"] <= ps["map.device_sort"]), ps
 
 # pipelined + device merge together, on a cluster
 p_both = dataclasses.replace(plan, reduce_merge_impl="device")

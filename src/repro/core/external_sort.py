@@ -28,8 +28,10 @@ Paper mapping (unchanged by the refactor):
       b"".join + np.concatenate staging copies. Each wave runs the
       in-memory two-stage streaming exoshuffle (core/streaming.py), after
       which every worker holds one globally range-partitioned sorted run;
-      shuffled payload rows are located by O(1) id-offset arithmetic
-      (gensort ids are contiguous per wave) instead of a per-wave argsort.
+      each shuffled record's row is located by O(1) id-offset arithmetic
+      (gensort ids are contiguous per wave) instead of a per-wave argsort,
+      and the run is gathered whole from the wave's row buffer straight
+      into its spill object.
 
   spill (§2.3): each worker's merged run is written back under
       plan.spill_prefix as one sorted run object. Against a TieredStore
@@ -358,8 +360,9 @@ class WaveSorter:
 
     def load_wave(self, store: StoreBackend, bucket: str, objs):
         """Chunked-GET a wave's input objects into one preallocated
-        interleaved-row buffer (zero-copy decode); returns (keys, ids,
-        payload)."""
+        interleaved-row buffer (zero-copy decode) and return it: the
+        (n, 2 + payload_words) u32 rows [key, id, payload...], the single
+        owner of the wave's bytes."""
         plan = self.plan
         n_wave = sum(
             (m.size - rec.HEADER_BYTES) // plan.record_bytes for m in objs)
@@ -370,7 +373,7 @@ class WaveSorter:
             for chunk in store.get_chunks(bucket, m.key, plan.store_chunk_bytes):
                 dec.feed(chunk)
             at += dec.finish()
-        return rec.split_rows(rows)
+        return rows
 
     def device_sort(self, keys, ids, *, timeline: PhaseTimeline | None = None,
                     tag: str = ""):
@@ -405,12 +408,18 @@ class WaveSorter:
         return sk, si, vcounts
 
     def encode_and_spill(self, store: StoreBackend, bucket: str, g: int,
-                         sk, si, vcounts, ids, payload, *,
+                         sk, si, vcounts, rows, *,
                          spiller: staging.AsyncWriter,
                          timeline: PhaseTimeline, tag: str,
                          offsets_out: dict, span: str = "map.encode") -> None:
         """Stage 2 of the map body: slice each mesh worker's run out of
-        the sorted wave, gather payload rows, encode, and spill.
+        the sorted wave and spill it, its body gathered whole from the
+        wave's row buffer `rows` (load_wave's).
+
+        A spilled run is the header plus the wave's rows in sorted order,
+        so each run is built with one gather of whole rows straight into
+        the spill object's buffer; the bytes equal
+        `encode_records(run keys, run ids, payload rows)`.
 
         Writes per-reducer offsets for every spilled run into
         `offsets_out[(g, wid)]` (they are also persisted in the spill
@@ -418,35 +427,45 @@ class WaveSorter:
         recover them from the store alone). `span` names the recorded
         compute segments — "map.encode" as a pipeline stage,
         "map.compute" from the monolithic compute_and_spill — which
-        leave out each run's map.spill_wait. Inside them, map.gather
-        times the id -> row lookup and the payload row gather, and
-        map.pack the record encode.
+        leave out each run's map.spill_wait. Inside them, map.pack times
+        the spill object's allocation and header, and map.gather the
+        id -> row lookup and the whole-row gather into the object; each
+        run's map.gather carries `lookup`, "offset" or "argsort".
         """
         plan, w, pw = self.plan, self.w, self.pw
+        ids = rows[:, 1]
         with timeline.span(span, worker=tag), \
                 timeline.span("map.gather", worker=tag):
-            # id -> wave row for gathering payload of shuffled records:
-            # O(1) offset arithmetic when the wave's ids are contiguous
-            # (the gensort layout), argsort gather otherwise.
-            id_base = _contiguous_id_base(ids) if pw else None
+            # id -> wave row of each shuffled record: O(1) offset
+            # arithmetic when the wave's ids are contiguous (the gensort
+            # layout), argsort lookup otherwise.
+            id_base = _contiguous_id_base(ids)
             order = sorted_ids = None
-            if pw and id_base is None:
+            if id_base is None:
                 order = np.argsort(ids)
                 sorted_ids = ids[order]
+        lookup = "argsort" if id_base is None else "offset"
         seg = sk.shape[0] // w
         for wid in range(w):
             with timeline.span(span, worker=tag):
                 n = int(vcounts[wid])
                 run_k = sk[wid * seg : wid * seg + n]
                 run_i = si[wid * seg : wid * seg + n]
-                run_p = None
-                if pw:
-                    with timeline.span("map.gather", worker=tag):
-                        if id_base is not None:
-                            sel = run_i.astype(np.int64) - id_base
-                        else:
-                            sel = order[np.searchsorted(sorted_ids, run_i)]
-                        run_p = payload[sel]
+                with timeline.span("map.pack", worker=tag):
+                    data = np.empty(rec.HEADER_BYTES + n * plan.record_bytes,
+                                    np.uint8)
+                    data[:rec.HEADER_BYTES] = np.frombuffer(
+                        rec.encode_header(n, pw), np.uint8)
+                with timeline.span("map.gather", worker=tag, lookup=lookup):
+                    if id_base is not None:
+                        sel = run_i.astype(np.int64) - id_base
+                    else:
+                        sel = order[np.searchsorted(sorted_ids, run_i)]
+                    # mode="clip" writes straight into `out` ("raise"
+                    # would buffer the whole run first).
+                    np.take(rows, sel, axis=0, mode="clip",
+                            out=data[rec.HEADER_BYTES:].view("<u4")
+                            .reshape(n, 2 + pw))
                 if self._local_bounds is not None:
                     internal = np.searchsorted(
                         run_k, self._local_bounds[wid], side="left")
@@ -454,8 +473,6 @@ class WaveSorter:
                     internal = np.empty((0,), np.int64)
                 offsets = np.concatenate(([0], internal, [n])).astype(np.int64)
                 offsets_out[(g, wid)] = offsets
-                with timeline.span("map.pack", worker=tag):
-                    data = rec.encode_records(run_k, run_i, run_p)
             # Submit each encoded run immediately: the AsyncWriter
             # backpressure bound (at most max_inflight encoded runs
             # in host memory) only holds if we never batch them.
@@ -469,19 +486,19 @@ class WaveSorter:
                                })
 
     def compute_and_spill(self, store: StoreBackend, bucket: str, g: int,
-                          keys, ids, payload, *, spiller: staging.AsyncWriter,
+                          rows, *, spiller: staging.AsyncWriter,
                           timeline: PhaseTimeline, tag: str,
                           offsets_out: dict) -> None:
-        """Sort wave g on the mesh and spill each mesh-worker's run —
-        the monolithic (non-pipelined) map body: device_sort +
-        encode_and_spill back to back on the calling thread, with the
-        original map.compute/map.spill_wait span structure."""
+        """Sort wave g (load_wave's `rows`) on the mesh and spill each
+        mesh-worker's run — the monolithic (non-pipelined) map body:
+        device_sort + encode_and_spill back to back on the calling
+        thread, with the original map.compute/map.spill_wait span
+        structure."""
         with timeline.span("map.compute", worker=tag):
-            sk, si, vcounts = self.device_sort(keys, ids)
-        self.encode_and_spill(store, bucket, g, sk, si, vcounts, ids,
-                              payload, spiller=spiller, timeline=timeline,
-                              tag=tag, offsets_out=offsets_out,
-                              span="map.compute")
+            sk, si, vcounts = self.device_sort(rows[:, 0], rows[:, 1])
+        self.encode_and_spill(store, bucket, g, sk, si, vcounts, rows,
+                              spiller=spiller, timeline=timeline, tag=tag,
+                              offsets_out=offsets_out, span="map.compute")
 
 
 def external_sort(

@@ -103,14 +103,14 @@ class PhaseTimeline:
         return self._annotation(phase)
 
     @contextlib.contextmanager
-    def span(self, phase: str, worker: str = ""):
+    def span(self, phase: str, worker: str = "", **attrs):
         """Time the with-block as one `phase` span on this thread.
 
         The span carries `cpu_s`, the thread's CPU seconds inside the
         block (its wall time less `cpu_s` is time off the CPU: the GIL,
-        locks, I/O, the scheduler), and shows in a profiler trace as a
-        TraceAnnotation. A block that raises records nothing: a failed
-        or abandoned attempt is not the stage's work.
+        locks, I/O, the scheduler), plus `attrs`, and shows in a profiler
+        trace as a TraceAnnotation. A block that raises records nothing:
+        a failed or abandoned attempt is not the stage's work.
         """
         with self._annotate(phase):
             t = time.perf_counter()
@@ -118,7 +118,7 @@ class PhaseTimeline:
             yield
             cpu = time.thread_time() - cpu
             end = time.perf_counter()
-        self.add(phase, t, end, worker=worker, cpu_s=cpu)
+        self.add(phase, t, end, worker=worker, cpu_s=cpu, **attrs)
 
     def totals(self) -> dict[str, float]:
         with self._lock:

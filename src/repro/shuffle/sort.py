@@ -74,24 +74,23 @@ class SortMapOp(MapOp):
 
     def process(self, store: StoreBackend, bucket: str, task: int, data, *,
                 spiller, timeline, tag) -> None:
-        keys, ids, payload = data
         self.sorter.compute_and_spill(
-            store, bucket, task, keys, ids, payload, spiller=spiller,
-            timeline=timeline, tag=tag, offsets_out=self.spill_offsets)
+            store, bucket, task, data, spiller=spiller, timeline=timeline,
+            tag=tag, offsets_out=self.spill_offsets)
 
     # Staged map interface (shuffle/runtime's pipelined executor, active
     # when plan.map_pipeline is true): the same body as process(), split
     # at the device boundary so wave N's sort overlaps wave N-1's encode.
+    # `data` is load()'s interleaved row buffer.
     def device_step(self, task: int, data, *, timeline, tag):
-        keys, ids, payload = data
-        return data, self.sorter.device_sort(keys, ids, timeline=timeline,
-                                             tag=tag)
+        return data, self.sorter.device_sort(data[:, 0], data[:, 1],
+                                             timeline=timeline, tag=tag)
 
     def encode_step(self, store: StoreBackend, bucket: str, task: int,
                     staged, *, spiller, timeline, tag) -> None:
-        (keys, ids, payload), (sk, si, vcounts) = staged
+        rows, (sk, si, vcounts) = staged
         self.sorter.encode_and_spill(
-            store, bucket, task, sk, si, vcounts, ids, payload,
+            store, bucket, task, sk, si, vcounts, rows,
             spiller=spiller, timeline=timeline, tag=tag,
             offsets_out=self.spill_offsets)
 

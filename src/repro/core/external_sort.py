@@ -98,8 +98,9 @@ import warnings
 from typing import Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.exoshuffle import ShuffleConfig
 from repro.core.streaming import streaming_sort
@@ -286,9 +287,9 @@ def _contiguous_id_base(ids: np.ndarray) -> int | None:
     return base
 
 
-def _untimed(phase: str, worker: str = ""):
+def _untimed(phase: str, worker: str = "", **attrs):
     """PhaseTimeline.span's stand-in where nothing records spans."""
-    return contextlib.nullcontext()
+    return contextlib.nullcontext(attrs)
 
 
 def _validate_plan(plan: ExternalSortPlan, w: int) -> None:
@@ -344,6 +345,9 @@ class WaveSorter:
 
         # A named function, so the device trace's module is jit_wave_sort.
         self._sort = jax.jit(wave_sort)
+        # The wave sort's input layout: each mesh worker's shard of keys
+        # and ids on its own device.
+        self._sharding = NamedSharding(mesh, P(axis))
         self._local_bounds = (
             np.asarray(self.cfg.keyspace.local_reducer_boundaries())
             if self.r1 > 1 else None
@@ -381,24 +385,36 @@ class WaveSorter:
         device lock), returned as host copies (sk, si, vcounts).
 
         With a timeline, the call is one map.device_sort span holding,
-        in order, map.device_lock (the acquire), map.h2d (keys and ids
-        to the device, waited for), map.sort (the jitted sort, waited
-        for) and map.d2h (the sorted run back to the host).
+        in order, map.device_lock (the acquire), map.h2d (each mesh
+        worker's shard of keys and ids to its own device, waited for),
+        map.sort (the jitted sort, waited for) and map.d2h (the sorted
+        run back to the host).
+
+        map.sort carries the exchange's counts: `mesh_workers`,
+        `exchange_slots` (the all-to-all's slots over every worker and
+        round, padding included; each worker sends every worker one
+        block of `block_capacity` slots a round) and `exchange_records`
+        (the records those slots carried, from the returned counts).
         """
         span = timeline.span if timeline is not None else _untimed
+        w, rounds = self.w, self.plan.num_rounds
+        slots = rounds * w * w * self.cfg.block_capacity(
+            keys.shape[0] // (w * rounds))
         with span("map.device_sort", worker=tag):
             with span("map.device_lock", worker=tag):
                 self._device_lock.acquire()
             try:
                 with span("map.h2d", worker=tag):
                     dk, di = jax.block_until_ready(
-                        (jnp.asarray(keys), jnp.asarray(ids)))
-                with span("map.sort", worker=tag):
+                        jax.device_put((keys, ids), self._sharding))
+                with span("map.sort", worker=tag, mesh_workers=w,
+                          exchange_slots=slots) as attrs:
                     sk, si, vcounts, ovf = jax.block_until_ready(
                         self._sort(dk, di))
+                    vcounts = np.asarray(vcounts)  # W counts, no sort work
+                    attrs["exchange_records"] = int(vcounts.sum())
                 with span("map.d2h", worker=tag):
-                    sk, si, vcounts = (np.asarray(sk), np.asarray(si),
-                                       np.asarray(vcounts))
+                    sk, si = np.asarray(sk), np.asarray(si)
             finally:
                 self._device_lock.release()
             if bool(np.asarray(ovf)):

@@ -109,13 +109,14 @@ class PhaseTimeline:
         The span carries `cpu_s`, the thread's CPU seconds inside the
         block (its wall time less `cpu_s` is time off the CPU: the GIL,
         locks, I/O, the scheduler), plus `attrs`, and shows in a profiler
-        trace as a TraceAnnotation. A block that raises records nothing:
-        a failed or abandoned attempt is not the stage's work.
+        trace as a TraceAnnotation. The block receives `attrs` and may
+        add the attributes it learns inside. A block that raises records
+        nothing: a failed or abandoned attempt is not the stage's work.
         """
         with self._annotate(phase):
             t = time.perf_counter()
             cpu = time.thread_time()  # read inside the wall interval
-            yield
+            yield attrs
             cpu = time.thread_time() - cpu
             end = time.perf_counter()
         self.add(phase, t, end, worker=worker, cpu_s=cpu, **attrs)

@@ -148,7 +148,7 @@ class ExternalSortPlan:
     merge-buffer bytes across all active reducers stay within the budget
     — not parallelism x partition size. (The budget governs the merge
     *buffers*; each active reducer additionally holds up to ~one encoded
-    output part being sliced plus max_inflight_writes parts awaiting
+    output part being filled plus max_inflight_writes parts awaiting
     upload.)
 
     map_pipeline overlaps each wave's host decode, device sort, and

@@ -231,7 +231,7 @@ def merge_fragments_device(frags, payload_words: int, *,
         pw = int(payload_words)
         return empty, empty, (np.empty((0, pw), np.uint32) if pw else None)
     if len(frags) == 1:
-        k, i, p, _ = frags[0]
+        k, i, p = frags[0][:3]
         return k, i, p
     total = sum(f[0].size for f in frags)
     assert total < 2**31, "emit window exceeds int32 ordinal range"

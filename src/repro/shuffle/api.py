@@ -268,6 +268,14 @@ class PartitionReducer(abc.ABC):
     #: the out-of-order multipart contract makes the upload order legal.
     deferred_part0: bool = False
 
+    #: How the last `consume` merged its fragments, which the scheduler
+    #: records as the `merge` attribute of each reduce.merge span:
+    #: "columns" (split into keys, ids and payload, then re-encoded), or,
+    #: for a sink that merges whole records (shuffle/sort._SortMergeSink),
+    #: "rows" (an argsort and a whole-row take) or "concat" (the
+    #: fragments did not interleave).
+    merge: str = "columns"
+
     # -- optional execution-context hook ---------------------------------
     #
     # A reducer may define
@@ -288,7 +296,9 @@ class PartitionReducer(abc.ABC):
     @abc.abstractmethod
     def consume(self, frags, *, final: bool) -> bytes:
         """Fold one emit cycle's per-run fragments (each a (keys, ids,
-        payload, k64) tuple of sorted arrays) into output body bytes."""
+        payload, k64, rows) tuple of sorted arrays; the first three are
+        column views of `rows`, the run's whole records) into output
+        body bytes: any bytes-like object, read before the next call."""
 
     def finalize(self) -> tuple[bytes, bytes | None]:
         """(tail body bytes, deferred part-0 bytes or None). Called once

@@ -186,8 +186,7 @@ class _ConcatSink(PartitionReducer):
         return rec.encode_header(self._n, self._pw)
 
     def consume(self, frags, *, final: bool) -> bytes:
-        return b"".join(rec.encode_body(k, i, p)
-                        for k, i, p, _k64 in frags if k.size)
+        return b"".join(rec.encode_body(*f[:3]) for f in frags if f[0].size)
 
 
 class RedirectReduceOp(ReduceOp):

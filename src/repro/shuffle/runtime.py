@@ -15,8 +15,9 @@ Memory contract (the reduce side): up to `slots` streaming reducers run
 concurrently, each holding at most `runs x chunk` decoded bytes, where
 chunks are granted by the AdaptiveBudgetGovernor out of the plan's
 global `reduce_memory_budget_bytes` — see the governor docstring for
-the provable bound. Encoded output parts being sliced/uploaded sit on
-top (~(1 + max_inflight_writes) x part bytes per active reducer).
+the provable bound. Output parts being filled or uploaded sit on top:
+pooled part buffers (PartPool), about (1 + max_inflight_writes) per
+active reducer, reused across a scheduler's partitions.
 
 Observability: PhaseTimeline keeps exact per-phase totals and forwards
 every span to its `sink` (usually obs/events.Tracer.timeline_sink()),
@@ -343,15 +344,18 @@ def reduce_chunking(plan, runs: int, active: int) -> tuple[int, int]:
 class RunCursor:
     """Bounded window over one spilled run's partition slice.
 
-    Holds at most `chunk_records` decoded records at a time; `refill`
-    issues one ranged GET for the next chunk, `take_upto` consumes the
-    buffered prefix that is safe to emit (every record <= bound). The
-    chunk size may be raised mid-stream (`set_chunk`) when the adaptive
-    governor re-apportions budget freed by retired reducers.
+    Holds at most `chunk_records` records at a time; `refill` issues one
+    ranged GET for the next chunk and keeps its body as whole rows
+    (`rows`, an (n, 2 + payload_words) u32 view of the response: no
+    copy), with `keys`, `ids` and `payload` as views of its columns;
+    `take_upto` consumes the buffered prefix that is safe to emit (every
+    record <= bound). The chunk size may be raised mid-stream
+    (`set_chunk`) when the adaptive governor re-apportions budget freed
+    by retired reducers.
     """
 
     __slots__ = ("_store", "_bucket", "_key", "_hi", "_next", "_chunk",
-                 "_pw", "k64", "keys", "ids", "payload")
+                 "_pw", "k64", "keys", "ids", "payload", "rows")
 
     def __init__(self, store, bucket, key, lo, hi, payload_words, chunk_records):
         self._store = store
@@ -361,9 +365,8 @@ class RunCursor:
         self._hi = int(hi)
         self._chunk = int(chunk_records)
         self._pw = int(payload_words)
-        self.keys = np.empty((0,), np.uint32)
-        self.ids = np.empty((0,), np.uint32)
-        self.payload = None
+        self.rows = rec.alloc_rows(0, self._pw)
+        self.keys, self.ids, self.payload = rec.split_rows(self.rows)
         self.k64 = np.empty((0,), np.uint64)
 
     @property
@@ -386,22 +389,36 @@ class RunCursor:
         start, length = rec.body_range(self._next, n, self._pw)
         body = self._store.get_range(self._bucket, self._key, start, length)
         self._next += n
-        k, i, p = rec.decode_body(body, self._pw)
-        self.keys, self.ids, self.payload = k, i, p
-        self.k64 = k.astype(np.uint64) << np.uint64(32) | i.astype(np.uint64)
+        self.rows = np.frombuffer(body, "<u4").reshape(-1, 2 + self._pw)
+        self.keys, self.ids, self.payload = rec.split_rows(self.rows)
+        k64 = self.keys.astype(np.uint64)
+        k64 <<= np.uint64(32)
+        k64 |= self.ids
+        self.k64 = k64
 
     def take_upto(self, bound):
-        """Consume and return the (keys, ids, payload, k64) prefix with
-        k64 <= bound; bound=None consumes everything buffered."""
+        """Consume and return the (keys, ids, payload, k64, rows) prefix
+        with k64 <= bound; bound=None consumes everything buffered."""
         cut = self.k64.size if bound is None else int(
             np.searchsorted(self.k64, bound, side="right"))
         out = (self.keys[:cut], self.ids[:cut],
                None if self.payload is None else self.payload[:cut],
-               self.k64[:cut])
-        self.keys, self.ids = self.keys[cut:], self.ids[cut:]
-        self.payload = None if self.payload is None else self.payload[cut:]
+               self.k64[:cut], self.rows[:cut])
+        self.rows = self.rows[cut:]
+        self.keys, self.ids, self.payload = rec.split_rows(self.rows)
         self.k64 = self.k64[cut:]
         return out
+
+
+def merge_order(k64s) -> np.ndarray | None:
+    """The merge of sorted fragments, given their packed keys in fragment
+    order: None when the fragments do not interleave (each ends at or
+    below the next one's start), so that their concatenation already is
+    the merge, else the stable argsort of the concatenated keys. Both
+    keep equal packed keys in fragment order."""
+    if all(k64s[i][-1] <= k64s[i + 1][0] for i in range(len(k64s) - 1)):
+        return None
+    return np.argsort(np.concatenate(k64s), kind="stable")
 
 
 def merge_fragments(frags, payload_words: int):
@@ -424,23 +441,19 @@ def merge_fragments(frags, payload_words: int):
         pw = int(payload_words)
         return empty, empty, (np.empty((0, pw), np.uint32) if pw else None)
     if len(frags) == 1:
-        k, i, p, _ = frags[0]
+        k, i, p = frags[0][:3]
         return k, i, p
-    # Fast path: the live fragments do not interleave (each ends at or
-    # below the next one's start) — common at the tail of skewed
-    # partitions, where a single run is left emitting. Concatenation IS
-    # the stable merge: a boundary tie keeps fragment order, exactly
-    # what the stable argsort below would produce, so the bytes are
-    # identical and the O(n log n) re-sort is skipped.
-    if all(frags[i][3][-1] <= frags[i + 1][3][0]
-           for i in range(len(frags) - 1)):
+    # Fast path (merge_order is None): the live fragments do not
+    # interleave — common at the tail of skewed partitions, where a
+    # single run is left emitting. Concatenation IS the stable merge, so
+    # the bytes are identical and the O(n log n) re-sort is skipped.
+    order = merge_order([f[3] for f in frags])
+    if order is None:
         keys = np.concatenate([f[0] for f in frags])
         ids = np.concatenate([f[1] for f in frags])
         payload = (np.concatenate([f[2] for f in frags])
                    if payload_words else None)
         return keys, ids, payload
-    k64 = np.concatenate([f[3] for f in frags])
-    order = np.argsort(k64, kind="stable")
     keys = np.concatenate([f[0] for f in frags])[order]
     ids = np.concatenate([f[1] for f in frags])[order]
     payload = None
@@ -504,11 +517,81 @@ class _AbandonGatedReads:
             yield chunk
 
 
+class PartPool:
+    """Reusable output-part buffers (`nbytes` u8 each) of one reduce
+    scheduler. A buffer is taken while a part fills and given back once
+    its upload returned, so parts after the first few land in memory the
+    process has already touched; the pool holds at most as many buffers
+    as were ever filling or uploading at once."""
+
+    def __init__(self, nbytes: int):
+        self.nbytes = int(nbytes)
+        self._free: list[np.ndarray] = []
+
+    def acquire(self) -> np.ndarray:
+        try:
+            return self._free.pop()  # atomic: safe against release()
+        except IndexError:
+            return np.empty(self.nbytes, np.uint8)
+
+    def release(self, buf: np.ndarray) -> None:
+        self._free.append(buf)
+
+
+class PartWriter:
+    """One partition's output byte stream, cut into `pool.nbytes` parts.
+
+    `write` copies bytes to their final offset in a pooled part buffer,
+    splitting them across part boundaries, so the parts are exactly the
+    fixed byte slices of the stream. Filled parts wait in `full` as
+    (index, buffer) until the caller uploads them; `close` returns the
+    last, partial part.
+    """
+
+    def __init__(self, pool: PartPool, first_index: int):
+        self._pool = pool
+        self._buf: np.ndarray | None = None
+        self._off = 0
+        self.next_index = int(first_index)
+        self.full: list[tuple[int, np.ndarray]] = []
+
+    def write(self, data) -> None:
+        src = np.frombuffer(data, np.uint8)
+        size = self._pool.nbytes
+        done = 0
+        while done < src.size:
+            if self._buf is None:
+                self._buf, self._off = self._pool.acquire(), 0
+            n = min(size - self._off, src.size - done)
+            self._buf[self._off:self._off + n] = src[done:done + n]
+            self._off += n
+            done += n
+            if self._off == size:
+                self.full.append((self.next_index, self._buf))
+                self.next_index += 1
+                self._buf = None
+
+    def close(self) -> tuple[int, np.ndarray, np.ndarray] | None:
+        """(index, filled view, buffer) of the last, partial part, or
+        None when the stream ended on a part boundary."""
+        buf, self._buf = self._buf, None
+        if buf is None:
+            return None
+        index, self.next_index = self.next_index, self.next_index + 1
+        return index, buf[:self._off], buf
+
+
 def timed_part(timeline: PhaseTimeline, tag: str, mp, index: int,
-               data: bytes) -> None:
-    """Background part upload, recorded as a reduce.upload span."""
-    with timeline.span("reduce.upload", worker=tag):
-        mp.put_part(index, data)
+               data, release: Callable[[], None] | None = None) -> None:
+    """Background part upload, recorded as a reduce.upload span;
+    `release` (the part buffer's return to its pool) runs once the
+    upload returned or raised."""
+    try:
+        with timeline.span("reduce.upload", worker=tag):
+            mp.put_part(index, data)
+    finally:
+        if release is not None:
+            release()
 
 
 def timed_put(timeline: PhaseTimeline, tag: str, store, bucket: str,
@@ -633,6 +716,11 @@ class ReduceScheduler:
         finishers = staging.AsyncWriter(
             max(plan.max_inflight_writes, self.width), max_workers=self.width,
             thread_name_prefix="reduce-finish")
+        # Output parts of every partition this scheduler writes share
+        # one buffer pool (one part size: the op's record width).
+        parts_pool = PartPool(
+            plan.output_part_records
+            * rec.record_bytes(shared.reduce_op.payload_words))
         dead_lock = threading.Lock()
         dead: list[BaseException] = []
         dead_evt = threading.Event()
@@ -649,7 +737,8 @@ class ReduceScheduler:
                 if r is None:
                     return
                 try:
-                    self._reduce_one(r, refill_pool, finishers, on_done)
+                    self._reduce_one(r, refill_pool, finishers, parts_pool,
+                                     on_done)
                 except SiblingFailed:
                     pass  # aborted cleanly; the root cause is recorded
                 except AttemptLost:
@@ -698,7 +787,7 @@ class ReduceScheduler:
 
     # -- internals ---------------------------------------------------------
 
-    def _reduce_one(self, r: int, refill_pool, finishers,
+    def _reduce_one(self, r: int, refill_pool, finishers, parts_pool,
                     on_done: Callable[[int], None] | None) -> None:
         # The whole partition body runs under its TraceContext: the
         # ranged GETs (inline or via the refill pool), part uploads and
@@ -709,9 +798,11 @@ class ReduceScheduler:
         with use_context(_task_context("reduce", f"r{r}", self.tag_prefix)), \
                 self.shared.timeline.span("reduce.partition",
                                           worker=f"{self.tag_prefix}r{r}"):
-            self._reduce_one_inner(r, refill_pool, finishers, on_done)
+            self._reduce_one_inner(r, refill_pool, finishers, parts_pool,
+                                  on_done)
 
     def _reduce_one_inner(self, r: int, refill_pool, finishers,
+                          parts_pool: PartPool,
                           on_done: Callable[[int], None] | None) -> None:
         shared = self.shared
         plan = shared.plan
@@ -721,7 +812,6 @@ class ReduceScheduler:
         governor = shared.governor
         pw = op.payload_words
         rb = rec.record_bytes(pw)
-        part_bytes = plan.output_part_records * rb
         tag = f"{self.tag_prefix}r{r}"
         slices, n_total = op.sources(r)
         registered = bool(slices)
@@ -755,12 +845,27 @@ class ReduceScheduler:
         mp = None
         uploader = None
 
-        def submit_part(data: bytes) -> None:
-            nonlocal next_part
-            idx, next_part = next_part, next_part + 1
-            # submit blocks under upload backpressure
+        def submit_part(index: int, data, buf=None) -> None:
+            # submit blocks under upload backpressure; a pooled buffer
+            # goes back to the pool once its upload returned.
+            release = (None if buf is None
+                       else (lambda: parts_pool.release(buf)))
             with timeline.span("reduce.upload_wait", worker=tag):
-                uploader.submit(timed_part, timeline, tag, mp, idx, data)
+                uploader.submit(timed_part, timeline, tag, mp, index, data,
+                                release)
+
+        def submit_full() -> None:
+            full, parts.full = parts.full, []
+            for index, buf in full:
+                submit_part(index, buf, buf)
+
+        def merge(frags, final: bool, attrs: dict) -> None:
+            # One emit cycle's merge, inside its reduce.merge span: the
+            # sink's bytes are copied once, straight into the parts.
+            body = sink.consume(frags, final=final)
+            if body:
+                parts.write(body)
+            attrs["merge"] = sink.merge
 
         try:
             cursors = [
@@ -789,8 +894,8 @@ class ReduceScheduler:
             # multipart contract (parts are assembled by index at
             # complete()) is what makes this legal.
             first_part = 1 if sink.deferred_part0 else 0
-            next_part = first_part
-            outbuf = bytearray(sink.begin())
+            parts = PartWriter(parts_pool, first_part)
+            parts.write(sink.begin())
             if sequential:
                 # Sequential drain: one cursor at a time, run slices
                 # forwarded to the sink in source order (deterministic —
@@ -811,15 +916,12 @@ class ReduceScheduler:
                             with timeline.span("reduce.fetch", worker=tag):
                                 c.refill()
                         shared.peak.update(akey, c.buffered_bytes)
-                        with timeline.span("reduce.merge", worker=tag):
+                        with timeline.span("reduce.merge",
+                                           worker=tag) as attrs:
                             frag = c.take_upto(None)
                             done = ci == len(cursors) - 1 and c.exhausted
-                            body = sink.consume([frag], final=done)
-                            if body:
-                                outbuf += body
-                        while len(outbuf) >= part_bytes:
-                            submit_part(bytes(outbuf[:part_bytes]))
-                            del outbuf[:part_bytes]
+                            merge([frag], done, attrs)
+                        submit_full()
                         if c.exhausted:
                             break
                 cursors = []
@@ -851,7 +953,7 @@ class ReduceScheduler:
                                 bind_context(RunCursor.refill), need))
                 shared.peak.update(akey,
                                    sum(c.buffered_bytes for c in cursors))
-                with timeline.span("reduce.merge", worker=tag):
+                with timeline.span("reduce.merge", worker=tag) as attrs:
                     # Safe emit bound: the smallest last-buffered key
                     # among runs that still have un-fetched records —
                     # nothing later can sort below it. When no run has
@@ -864,30 +966,27 @@ class ReduceScheduler:
                     bound = min(remote_tails) if remote_tails else None
                     frags = [c.take_upto(bound) for c in cursors]
                     cursors = [c for c in cursors if not c.exhausted]
-                    body = sink.consume(frags, final=bound is None)
-                    if body:
-                        outbuf += body
-                while len(outbuf) >= part_bytes:
-                    submit_part(bytes(outbuf[:part_bytes]))
-                    del outbuf[:part_bytes]
+                    merge(frags, bound is None, attrs)
+                submit_full()
             # finalize can block on real merge work (the device sink's
             # in-flight window) — record it under reduce.merge so the
             # span is the COMPLETE scheduler-visible merge cost.
-            with timeline.span("reduce.merge", worker=tag):
+            with timeline.span("reduce.merge", worker=tag) as attrs:
                 tail, part0 = sink.finalize()
+                attrs["merge"] = sink.merge
             if tail:
-                outbuf += tail
-                while len(outbuf) >= part_bytes:
-                    submit_part(bytes(outbuf[:part_bytes]))
-                    del outbuf[:part_bytes]
+                parts.write(tail)
+                submit_full()
             # >= 1 part always: a partition with no body bytes still
             # uploads its header (inline for header-first sinks, as the
             # deferred part 0 below otherwise).
-            if outbuf or (next_part == first_part and part0 is None):
-                submit_part(bytes(outbuf))
+            last = parts.close()
+            if last is not None:
+                submit_part(*last)
+            elif parts.next_index == first_part and part0 is None:
+                submit_part(first_part, b"")
             if part0 is not None:
-                with timeline.span("reduce.upload_wait", worker=tag):
-                    uploader.submit(timed_part, timeline, tag, mp, 0, part0)
+                submit_part(0, part0)
         except BaseException:
             # Setup, merge, or upload died mid-session: let in-flight
             # parts settle, then discard the session — never commit it.
@@ -1109,6 +1208,8 @@ __all__ = [
     "AttemptLost",
     "JobControl",
     "PeakTracker",
+    "PartPool",
+    "PartWriter",
     "PhaseTimeline",
     "ReduceScheduler",
     "ReduceShared",
@@ -1116,6 +1217,7 @@ __all__ = [
     "SiblingFailed",
     "finalize_session",
     "merge_fragments",
+    "merge_order",
     "reduce_chunking",
     "run_map_tasks",
     "timed_part",

@@ -4,8 +4,8 @@ The paper's 100 TB sort is, in library terms, nothing special: a MapOp
 that loads a wave of input objects and mesh-sorts it into range-
 partitioned spill runs (wrapping core/external_sort.WaveSorter — the
 device kernels, zero-copy load, and spill layout are unchanged), and a
-ReduceOp whose PartitionReducer is a pure streaming k-way merge (the
-identical runtime.merge_fragments body the monolithic driver used).
+ReduceOp whose PartitionReducer is a pure streaming k-way merge of
+whole records (the same order and bytes as core/external_sort's reduce).
 Output bytes are byte- and etag-identical to the pre-refactor drivers at
 any parallelism, worker count, and under worker kills — asserted by
 tests/test_cluster.py and tests/test_shuffle.py.
@@ -29,7 +29,7 @@ from repro.io.backends import StoreBackend
 from repro.shuffle.api import MapOp, PartitionReducer, ReduceOp
 from repro.shuffle.job import ShuffleJob
 from repro.shuffle.partition import RangePartitioner
-from repro.shuffle.runtime import merge_fragments
+from repro.shuffle.runtime import merge_order
 
 
 class SortMapOp(MapOp):
@@ -99,20 +99,47 @@ class _SortMergeSink(PartitionReducer):
     """Streaming k-way merge: the record count is known up front (sum of
     run-slice lengths), so the header streams first and sorted body
     chunks follow — exactly the monolithic reduce body, hence exactly
-    its bytes."""
+    its bytes.
+
+    The merge keeps records whole: each cycle's merged rows land in one
+    window buffer, reused across cycles, never split into columns."""
 
     deferred_part0 = False
+    merge = "concat"  # until a cycle interleaves
 
     def __init__(self, n_total: int, payload_words: int):
         self._n = int(n_total)
         self._pw = int(payload_words)
+        self._joined = self._window = rec.alloc_rows(0, self._pw)
 
     def begin(self) -> bytes:
         return rec.encode_header(self._n, self._pw)
 
-    def consume(self, frags, *, final: bool) -> bytes:
-        mk, mi, mp = merge_fragments(frags, self._pw)
-        return rec.encode_body(mk, mi, mp) if mk.size else b""
+    def consume(self, frags, *, final: bool):
+        """The cycle's merged rows, as a bytes view valid until the next
+        call. `merge` "concat" when the live fragments do not interleave
+        (their rows, in fragment order); "rows" otherwise: one stable
+        argsort of the packed keys (ties keep fragment order) and one
+        whole-row take from the fragments' rows laid end to end."""
+        frags = [f for f in frags if f[3].size]
+        order = merge_order([f[3] for f in frags])
+        self.merge = "concat" if order is None else "rows"
+        if not frags:
+            return b""
+        if len(frags) == 1:
+            return memoryview(frags[0][4]).cast("B")
+        n = sum(f[3].size for f in frags)
+        if self._window.shape[0] < n:
+            self._joined = rec.alloc_rows(n, self._pw)
+            self._window = rec.alloc_rows(n, self._pw)
+        window = self._window[:n]
+        if order is None:
+            np.concatenate([f[4] for f in frags], out=window)
+        else:
+            joined = self._joined[:n]
+            np.concatenate([f[4] for f in frags], out=joined)
+            np.take(joined, order, axis=0, mode="clip", out=window)
+        return memoryview(window).cast("B")
 
 
 class _DeviceMergeSink(PartitionReducer):
